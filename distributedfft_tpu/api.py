@@ -194,6 +194,12 @@ class OpPlan3D(Plan3D):
     multiplier: Any = None
 
 
+def _executor_env() -> str:
+    """``DFFT_EXECUTOR``, or "" when unset or off."""
+    env = os.environ.get("DFFT_EXECUTOR", "").strip()
+    return "" if env in ("0", "none") else env
+
+
 def _default_executor(executor: str) -> str:
     """Resolve the planner's executor default: ``DFFT_EXECUTOR`` (when
     set) replaces the built-in ``"xla"`` default — the documented escape
@@ -204,8 +210,34 @@ def _default_executor(executor: str) -> str:
     is part of the plan-cache key."""
     if executor != "xla":
         return executor
-    env = os.environ.get("DFFT_EXECUTOR", "").strip()
-    return env if env and env not in ("0", "none") else executor
+    return _executor_env() or executor
+
+
+def _on_tpu() -> bool:
+    """The backend predicate of :func:`_single_default_executor`."""
+    return jax.default_backend() == "tpu"
+
+
+def _single_default_executor(shape, dtype, batch) -> str:
+    """The executor a single-device c2c plan gets when the caller names
+    none: ``pallas`` on the TPU for an unbatched complex64 plan whose
+    every axis takes a plane pass (``pallas_fft.plane_pass_eligible``):
+    one complex64 split, three in-place passes, one combine. On a TPU
+    v5e it ran 1.2x (128^3) to 5x (384^3) faster than XLA's FFT in
+    both directions, on every grid tried (axes of 128 to 1024). ``xla``
+    everywhere else. Counted in ``c2c_default_pallas``. The plan runs
+    it at HIGHEST whatever ``DFFT_MM_PRECISION`` says
+    (``plan_dft_c2c_3d``)."""
+    if (batch is not None or jnp.dtype(dtype) != jnp.complex64
+            or not _on_tpu()):
+        return "xla"
+    from .ops import pallas_fft
+
+    if not all(pallas_fft.plane_pass_eligible(shape, ax)
+               for ax in range(len(shape))):
+        return "xla"
+    _metrics.inc("c2c_default_pallas", shape="x".join(map(str, shape)))
+    return "pallas"
 
 
 def _resolve_options(
@@ -542,7 +574,7 @@ def plan_dft_c2c_3d(
     *,
     direction: int = FORWARD,
     decomposition: str | None = None,
-    executor: str = "xla",
+    executor: str | None = None,
     dtype: Any = None,
     donate: bool = False,
     algorithm: str = "alltoall",
@@ -559,6 +591,11 @@ def plan_dft_c2c_3d(
     batch: int | None = None,
 ) -> Plan3D:
     """Create a distributed 3D complex-to-complex FFT plan.
+
+    ``executor=None`` (the default) is ``DFFT_EXECUTOR`` when set, else
+    ``xla``, except on a single device, where
+    :func:`_single_default_executor` picks ``pallas`` for the shapes its
+    plane passes take; ``plan.executor`` names the one resolved.
 
     ``mesh`` may be a :class:`jax.sharding.Mesh` (1D -> slab, 2D -> pencil),
     an int (decomposition chosen by :func:`~.plan_logic.choose_decomposition`
@@ -639,10 +676,11 @@ def plan_dft_c2c_3d(
     if batch is not None and (in_spec is not None or out_spec is not None):
         raise ValueError("batched plans take the canonical chain layouts; "
                          "in_spec/out_spec require batch=None (or 1)")
-    opts = _resolve_options(decomposition, executor, donate, algorithm,
-                            options, overlap_chunks, tune, wire_dtype,
-                            max_roundtrip_err, mm_precision, mm_complex,
-                            fuse)
+    defaulted = executor is None and options is None and not _executor_env()
+    opts = _resolve_options(decomposition, executor or "xla", donate,
+                            algorithm, options, overlap_chunks, tune,
+                            wire_dtype, max_roundtrip_err, mm_precision,
+                            mm_complex, fuse)
     if resolve_tune_mode(opts.tune) != "off":
         from . import tuner
 
@@ -667,7 +705,18 @@ def plan_dft_c2c_3d(
         raise ValueError("in_spec/out_spec require a mesh")
 
     if lp.decomposition == "single":
-        ex = get_executor(opts.executor)
+        label = opts.executor
+        if defaulted:
+            import dataclasses
+
+            ex = _single_default_executor(shape, dtype, batch)
+            opts = dataclasses.replace(opts, executor=ex)
+            lp = dataclasses.replace(
+                lp, options=dataclasses.replace(lp.options, executor=ex))
+            # The default plan is exact, as XLA's FFT is: its pallas
+            # contractions run at HIGHEST, not at DFFT_MM_PRECISION.
+            label = "pallas:highest" if ex == "pallas" else ex
+        ex = get_executor(label)
         fft_axes = (0, 1, 2) if batch is None else (1, 2, 3)
         fn = jax.jit(lambda x: ex(x, fft_axes, forward))
         spec = None

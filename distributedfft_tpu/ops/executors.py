@@ -15,10 +15,13 @@ any of them can be dropped into the distributed pipeline under ``jit`` /
 - ``"matmul"`` — mixed-radix DFT-by-matrix-multiply on the MXU
   (:mod:`distributedfft_tpu.ops.dft_matmul`), the TPU-idiomatic analog of
   templateFFT's runtime-generated Stockham kernels.
-- ``"pallas"`` — the fused four-step Pallas kernel
-  (:mod:`distributedfft_tpu.ops.pallas_fft`): whole-axis transform staged
-  through VMEM in one kernel, one HBM read/write per axis; falls back to
-  ``"matmul"`` for ineligible lengths/dtypes.
+- ``"pallas"`` — the Pallas kernels
+  (:mod:`distributedfft_tpu.ops.pallas_fft`): a complex64 operand is split
+  into float32 planes once, each axis is one in-place pass staged through
+  VMEM (one HBM read/write per axis), and the planes are combined once;
+  falls back to ``"matmul"`` for ineligible lengths/dtypes. The
+  single-device c2c planner's default on the TPU where every axis takes
+  a plane pass (``api._single_default_executor``).
 """
 
 from __future__ import annotations
@@ -535,14 +538,16 @@ register_real_executor("matmul", _matmul_r2c, _matmul_c2r)
 
 
 def _pallas_executor(x: Array, axes: Sequence[int], forward: bool = True) -> Array:
+    import jax.lax as lax
+
     from . import pallas_fft
 
     axes = tuple(axes)
+    c64 = jnp.dtype(x.dtype) == jnp.complex64 and x.size > 0
     # Fuse a trailing 2D plane into one kernel launch (the templateFFT
     # 2D-app role for the t0 stage): both axes transform through VMEM with
     # one HBM read/write instead of two of each.
-    if (len(axes) >= 2 and jnp.dtype(x.dtype) == jnp.complex64
-            and x.size > 0
+    if (len(axes) >= 2 and c64
             and {axes[-2] % x.ndim, axes[-1] % x.ndim}
             == {x.ndim - 2, x.ndim - 1}):
         if pallas_fft.eligible2d(x.shape[-2], x.shape[-1]):
@@ -555,9 +560,17 @@ def _pallas_executor(x: Array, axes: Sequence[int], forward: bool = True) -> Arr
 
             _metrics.inc("pallas_plane_per_axis", ny=x.shape[-2],
                          nz=x.shape[-1])
-    for ax in axes:
-        x = pallas_fft.fft_along_axis(x, ax, forward=forward)
-    return x
+    if not c64:
+        for ax in axes:
+            x = pallas_fft.fft_along_axis(x, ax, forward=forward)
+        return x
+    if not axes:
+        return x
+    # complex64: split into float32 planes once, transform every axis on
+    # the planes, combine once.
+    re, im = pallas_fft.fft_planes(jnp.real(x), jnp.imag(x), axes,
+                                   forward=forward)
+    return lax.complex(re, im)
 
 
 register_executor("pallas", _pallas_executor)

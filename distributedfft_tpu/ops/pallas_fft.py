@@ -28,6 +28,11 @@ Scope: complex64, composite n with a balanced split n1*n2 (n1, n2 <= 256 —
 one kernel covers n up to 65536; longer axes fall back to the recursive
 matmul executor). The inverse is the conjugate-matrix kernel with the 1/n
 scale applied by the caller (numpy convention, like every executor here).
+
+The ``pallas`` executor's complex64 path is :func:`fft_planes` (the last
+section): float32 planes in and out, one in-place pass per axis whose
+length is r * 128 (r <= 8), factored so the MXU contracts 128 wide; other
+axes take :func:`fft_along_axis` above.
 """
 
 from __future__ import annotations
@@ -815,3 +820,278 @@ def fft_along_axis(x: jnp.ndarray, axis: int, forward: bool = True) -> jnp.ndarr
     if moved:
         y = jnp.moveaxis(y, -1, axis)
     return y
+
+
+# --------------------------------------------------------------------------
+# Plane-resident axis passes: the executor's path for complex64.
+#
+# The executor splits a complex64 operand into float32 (re, im) planes once,
+# runs one pass per axis on the planes, and combines once. Each pass is one
+# pallas_call that reads a tile of both planes from HBM, transforms it along
+# the axis in VMEM and writes it back IN PLACE (input_output_aliases), so
+# one plane pair is all the extra memory a transform holds.
+#
+# The axis is factored n = r * m with m = 128, the MXU width (decimation in
+# time, j = j1 + r*j2, k = k2 + m*k1):
+#
+#     Y[j1, k2] = sum_j2 W_m[k2, j2] x[j1 + r*j2]      (MXU, 128-wide)
+#     Y[j1, k2] *= w_n^{j1*k2}                         (VPU twiddle)
+#     X[k2 + m*k1] = sum_j1 w_r^{j1*k1} Y[j1, k2]      (VPU radix-r)
+#
+# so the output rows come out in natural order in r contiguous blocks, and
+# the input rows j1 + r*j2 are a sublane-strided load. Mosaic strides only a
+# memref whose last dim is 128, so every kind of tile ends in 128 lanes:
+#
+#   "lane"    the last axis: [g*128, n] row tiles, transposed 128 rows at a
+#             time into a [g, n, 128] VMEM scratch, transposed back on store;
+#   "sublane" the second-minor axis: [g, n, 128] tiles;
+#   "major"   any axis before it: [n, g, 128] tiles (the axis is a major
+#             dim, each column a sublane of every row).
+#
+# Each complex product with the DFT matrix is three real matmuls (Gauss),
+# at mm_precision() (HIGHEST: f32-exact multi-pass on the MXU).
+
+#: The MXU contraction width of the plane passes' dense stage.
+PLANE_WIDTH = 128
+
+#: Largest radix the plane passes run on the VPU (n <= 8 * 128).
+PLANE_MAX_RADIX = 8
+
+#: Tile groups (of 128 rows or lanes) per grid step: 2 MiB per plane at
+#: n = 512 (on a TPU v5e, 8 and 16 groups ran a 512^3 pass in the same
+#: time).
+_PLANE_GROUPS = 8
+
+
+def plane_factor(n: int) -> tuple[int, int] | None:
+    """(r, m) with n = r * m, m the MXU width, or None: the lengths the
+    plane passes take."""
+    r, rem = divmod(n, PLANE_WIDTH)
+    if rem or not 1 <= r <= PLANE_MAX_RADIX:
+        return None
+    return r, PLANE_WIDTH
+
+
+def _groups(extent: int, unit: int) -> int | None:
+    """Largest g in 8, 4, 2, 1 with ``extent`` divisible by g * unit."""
+    for g in (_PLANE_GROUPS, 4, 2, 1):
+        if extent % (g * unit) == 0:
+            return g
+    return None
+
+
+def _plane_layout(shape, axis: int):
+    """How a plane pass tiles ``shape`` along ``axis``: (kind, g, view,
+    block, grid, index_map), or None where it cannot. ``view`` is a
+    reshape that keeps XLA's (8, 128) tiling of the last two dims, so it
+    costs no copy."""
+    nd = len(shape)
+    axis %= nd
+    n = shape[axis]
+    if plane_factor(n) is None:
+        return None
+    lanes = shape[-1]
+    if axis == nd - 1:
+        rows = math.prod(shape[:-1])
+        g = _groups(rows, PLANE_WIDTH)
+        if g is None or (nd > 2 and shape[-2] % 8):
+            return None
+        return ("lane", g, (rows, n), (g * PLANE_WIDTH, n),
+                (rows // (g * PLANE_WIDTH),), lambda i: (i, 0))
+    if lanes % PLANE_WIDTH:
+        return None
+    lead = math.prod(shape[:axis])
+    if axis == nd - 2:
+        g = _groups(lead, 1)
+        return ("sublane", g, (lead, n, lanes), (g, n, PLANE_WIDTH),
+                (lead // g, lanes // PLANE_WIDTH), lambda a, c: (a, 0, c))
+    mid = math.prod(shape[axis + 1:-1])
+    if mid % _PLANE_GROUPS or (mid != shape[-2] and shape[-2] % 8):
+        return None
+    return ("major", _PLANE_GROUPS, (lead, n, mid, lanes),
+            (1, n, _PLANE_GROUPS, PLANE_WIDTH),
+            (lead, mid // _PLANE_GROUPS, lanes // PLANE_WIDTH),
+            lambda a, b, c: (a, 0, b, c))
+
+
+def plane_pass_eligible(shape, axis: int) -> bool:
+    """True where :func:`fft_planes` transforms ``axis`` of a ``shape``
+    plane pair with one in-place plane pass (other axes take the
+    complex per-axis path)."""
+    return _plane_layout(tuple(shape), axis) is not None
+
+
+@functools.lru_cache(maxsize=None)
+def _plane_tables(n: int, forward: bool):
+    """float32 LUTs of one plane pass, host-exact in float64: the m-point
+    DFT matrix W[k2, j2] as re, im and re + im (the Gauss product), and
+    the twiddles w_n^{j1*k2} as [r, m, 1] columns. The inverse's 1/n is
+    folded into W."""
+    r, m = plane_factor(n)
+    sign = -2j if forward else 2j
+    k = np.arange(m)
+    w = np.exp(sign * np.pi * np.outer(k, k) / m)
+    if not forward:
+        w = w / n
+    t = np.exp(sign * np.pi * np.outer(np.arange(r), k) / n)[:, :, None]
+    f32 = lambda a: np.ascontiguousarray(a, np.float32)  # noqa: E731
+    return (f32(w.real), f32(w.imag), f32(w.real + w.imag),
+            f32(t.real), f32(t.imag))
+
+
+def _radix(ys, forward: bool):
+    """The r-point DFT across ``ys`` = [(re, im)] * r on the VPU, with
+    the trace-time coefficients 1, -1, +-i applied as sign flips."""
+    r = len(ys)
+    sign = -2j if forward else 2j
+    outs = []
+    for k1 in range(r):
+        acc = None
+        for j1, (yr, yi) in enumerate(ys):
+            c = np.exp(sign * np.pi * ((j1 * k1) % r) / r)
+            cr, ci = round(c.real, 12), round(c.imag, 12)
+            if (cr, ci) == (1, 0):
+                term = (yr, yi)
+            elif (cr, ci) == (-1, 0):
+                term = (-yr, -yi)
+            elif (cr, ci) == (0, 1):
+                term = (-yi, yr)
+            elif (cr, ci) == (0, -1):
+                term = (yi, -yr)
+            else:
+                cr, ci = np.float32(c.real), np.float32(c.imag)
+                term = (yr * cr - yi * ci, yr * ci + yi * cr)
+            acc = term if acc is None else (acc[0] + term[0],
+                                            acc[1] + term[1])
+        outs.append(acc)
+    return outs
+
+
+def _make_plane_kernel(n: int, forward: bool, kind: str, g: int,
+                       interpret: bool):
+    """One plane pass over a tile (see the section comment for the three
+    ``kind`` layouts): per column group of 128 lanes, r sublane-strided
+    loads of 128 rows, the Gauss matmuls and twiddle per j1, the radix-r
+    combine, and r stores of 128 rows.
+
+    A "major" tile [1, n, g, 128] is read as the [n*g, 128] rows it is
+    in VMEM, so that row j of group c is row j*g + c and Mosaic strides
+    it on the sublanes (indexed in 4D, Mosaic gathers each vreg with
+    eight loads, rotates and selects, and stores it one sublane at a
+    time). The interpreter cannot store through a reshaped ref, so there
+    it indexes the 4D tile."""
+    r, m = plane_factor(n)
+
+    def rows(start, stride):
+        return pl.ds(start, m, stride=stride) if stride > 1 else pl.ds(
+            start, m)
+
+    if kind == "major" and interpret:
+        def get(ref, j1, c):
+            return ref[0, rows(j1, r), c, :]
+
+        def put(ref, k1, c, v):
+            ref[0, k1 * m:(k1 + 1) * m, c, :] = v
+    elif kind == "major":
+        def get(ref, j1, c):
+            return ref.reshape(n * g, m)[rows(j1 * g + c, r * g), :]
+
+        def put(ref, k1, c, v):
+            ref.reshape(n * g, m)[rows(k1 * m * g + c, g), :] = v
+    elif kind == "sublane":
+        def get(ref, j1, c):
+            return ref[c, rows(j1, r), :]
+
+        def put(ref, k1, c, v):
+            ref[c, k1 * m:(k1 + 1) * m, :] = v
+    else:
+        def get(ref, j1, c):
+            return ref[c, rows(j1, r), :]
+
+        def put(ref, k1, c, v):
+            ref[c * m:(c + 1) * m, k1 * m:(k1 + 1) * m] = v.T
+
+    def kernel(wr, wi, ws, tr, ti, xr, xi, yr, yi, *scratch):
+        src = (xr, xi)
+        if kind == "lane":
+            # Rows to sublanes: [128, n] -> [n, 128] per group.
+            for c in range(g):
+                for x, t in zip(src, scratch):
+                    t[c] = x[c * m:(c + 1) * m, :].T
+            src = scratch
+        w_r, w_i, w_s = wr[...], wi[...], ws[...]
+
+        def load(ref, j1):
+            cols = [get(ref, j1, c) for c in range(g)]
+            return cols[0] if g == 1 else jnp.concatenate(cols, axis=1)
+
+        ys = []
+        for j1 in range(r):
+            ar, ai = load(src[0], j1), load(src[1], j1)
+            p, q, s = _mm(w_r, ar), _mm(w_i, ai), _mm(w_s, ar + ai)
+            br, bi = p - q, s - p - q
+            if j1:
+                cr, ci = tr[j1], ti[j1]
+                br, bi = br * cr - bi * ci, br * ci + bi * cr
+            ys.append((br, bi))
+        for k1, (zr, zi) in enumerate(_radix(ys, forward)):
+            for c in range(g):
+                put(yr, k1, c, zr[:, c * m:(c + 1) * m])
+                put(yi, k1, c, zi[:, c * m:(c + 1) * m])
+
+    return kernel
+
+
+def _plane_pass(re, im, axis: int, forward: bool):
+    """One in-place plane pass of ``axis`` (:func:`plane_pass_eligible`);
+    the outputs alias the plane operands, so XLA reuses their buffers
+    wherever the caller has no other use for them."""
+    shape = re.shape
+    kind, g, view, block, grid, index_map = _plane_layout(shape, axis)
+    n = shape[axis % len(shape)]
+    vma = _vma(re)
+    consts = [jnp.asarray(t) for t in _plane_tables(n, forward)]
+    if vma:
+        consts = [pvary(c, tuple(vma)) for c in consts]
+    lut_specs = [pl.BlockSpec(c.shape, lambda *_, nd=c.ndim: (0,) * nd)
+                 for c in consts]
+    spec = pl.BlockSpec(block, index_map)
+    scratch = ([pltpu.VMEM((g, n, PLANE_WIDTH), jnp.float32)] * 2
+               if kind == "lane" else [])
+    size = math.prod(shape)
+    interpret = _interpret_mode()
+    yr, yi = pl.pallas_call(
+        _make_plane_kernel(n, forward, kind, g, interpret),
+        grid=grid,
+        in_specs=lut_specs + [spec, spec],
+        out_specs=(spec, spec),
+        out_shape=(shape_dtype_struct(view, jnp.float32, vma=vma),) * 2,
+        scratch_shapes=scratch,
+        input_output_aliases={len(consts): 0, len(consts) + 1: 1},
+        cost_estimate=pl.CostEstimate(
+            flops=6 * PLANE_WIDTH * size, bytes_accessed=16 * size,
+            transcendentals=0),
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("parallel",) * len(grid),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=f"fft_plane_{kind}",
+    )(*consts, re.reshape(view), im.reshape(view))
+    return yr.reshape(shape), yi.reshape(shape)
+
+
+def fft_planes(re, im, axes, forward: bool = True):
+    """C2C FFT of the complex array ``re + i*im`` over ``axes``, float32
+    planes in and out: the executor's complex64 path. Eligible axes take
+    one in-place plane pass each; the others take
+    :func:`fft_along_axis` (today's balanced-split kernels, or the
+    counted matmul fallback), whose complex round trip XLA folds away.
+    Forward unnormalized, inverse scaled by 1/n per axis."""
+    interpret_vma = _interpret_mode() and bool(_vma(re))
+    for ax in axes:
+        if not interpret_vma and plane_pass_eligible(re.shape, ax):
+            re, im = _plane_pass(re, im, ax, forward)
+        else:
+            y = fft_along_axis(lax.complex(re, im), ax, forward=forward)
+            re, im = jnp.real(y), jnp.imag(y)
+    return re, im

@@ -12,6 +12,7 @@ this test process, and all of them live in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 import distributedfft_tpu as dfft
 from distributedfft_tpu.ops import dft_matmul, pallas_fft
+from distributedfft_tpu.ops.executors import get_executor
 from distributedfft_tpu.parallel.mesh import PENCIL_AXES, SLAB_AXIS
 
 N = 512
@@ -125,6 +127,45 @@ def test_pallas_1d_kernels_512_compile(one_chip, real_kernels, kernel):
     x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     compiled = jax.jit(fn).lower(x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["fwd", "bwd"])
+def test_pallas_plane_path_512(one_chip, real_kernels, forward):
+    """The single-device default on the TPU at 512^3 (the planner sees
+    the CPU here, so the executor is compiled itself): one complex64
+    split, three in-place plane passes, one combine, no FFT op, and no
+    more temporary memory than the ``xla`` plan."""
+    x = jax.ShapeDtypeStruct((N,) * 3, jnp.complex64, sharding=one_chip)
+    ex = get_executor("pallas")
+    lowered = jax.jit(lambda v: ex(v, (0, 1, 2), forward)).lower(x)
+    assert "stablehlo.fft" not in lowered.as_text()
+    compiled = lowered.compile()
+    targets = re.findall(r'custom_call_target="([^"]+)"', compiled.as_text())
+    assert sorted(targets) == sorted(
+        ["X64SplitHigh", "X64SplitLow", "X64Combine"]
+        + ["tpu_custom_call"] * 3), targets
+    xla_plan = dfft.plan_dft_c2c_3d((N,) * 3, None, dtype=jnp.complex64,
+                                    executor="xla")
+    xla_temp = _compile_plan(xla_plan, one_chip).memory_analysis()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= xla_temp.temp_size_in_bytes, (temp, xla_temp)
+
+
+@pytest.mark.parametrize("radix", range(1, pallas_fft.PLANE_MAX_RADIX + 1))
+@pytest.mark.parametrize("kind", ["major", "sublane", "lane"])
+def test_plane_pass_compiles_each_kind_and_radix(one_chip, real_kernels,
+                                                 kind, radix):
+    """Every tile kind at every radix the default rule admits (axis
+    lengths 128..1024), one in-place pass each, on the tile groups a
+    large grid gets (eight per grid step)."""
+    n = radix * pallas_fft.PLANE_WIDTH
+    shape, axis = {"major": ((n, 8, 128), 0), "sublane": ((8, n, 128), 1),
+                   "lane": ((128, 8, n), 2)}[kind]
+    assert pallas_fft._plane_layout(shape, axis)[:2] == (kind, 8)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda a, b: pallas_fft._plane_pass(
+        a, b, axis, True)).lower(x, x).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_pallas_2d_plane_kernel(one_chip, real_kernels, monkeypatch):
