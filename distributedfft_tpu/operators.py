@@ -42,6 +42,10 @@ indexing, times ``n``). Scale the operator parameters for other box
 lengths (e.g. a physical Poisson solve on ``[0, L)^3`` divides the
 result by ``(2*pi/L)^-2`` — equivalently pre-scale ``f``).
 
+A real field takes ``real=True`` (one device): r2c, the multiplier on
+the half spectrum, c2r, in one program at half the work of the complex
+route (:func:`plan_spectral_op`).
+
 See ``docs/OPERATORS.md`` for the operator menu and the fusion model.
 """
 
@@ -60,7 +64,7 @@ import jax.numpy as jnp
 from . import api as _api
 from .api import FORWARD, OpPlan3D
 from .geometry import world_box
-from .ops.executors import get_executor
+from .ops.executors import get_c2r, get_executor, get_r2c
 from .parallel.pencil import build_pencil_spectral_op
 from .parallel.slab import apply_multiplier, build_slab_spectral_op
 from .plan_logic import logic_plan3d, resolve_tune_mode, stage_layouts
@@ -339,11 +343,15 @@ def _multiplier_fn(op: SpectralOp, shape, cdtype) -> Callable:
     raise ValueError(f"unknown SpectralOp kind {op.kind!r}")
 
 
-def _full_grids(shape) -> tuple:
+def _full_grids(shape, n2_kept: int | None = None) -> tuple:
+    """Broadcastable int32 index grids of the world spectrum; with
+    ``n2_kept`` only the first ``n2_kept`` indices of axis 2 (the half
+    spectrum ``n2//2 + 1`` of a real field)."""
     n0, n1, n2 = (int(s) for s in shape)
     return (jnp.arange(n0, dtype=jnp.int32)[:, None, None],
             jnp.arange(n1, dtype=jnp.int32)[None, :, None],
-            jnp.arange(n2, dtype=jnp.int32)[None, None, :])
+            jnp.arange(n2 if n2_kept is None else n2_kept,
+                       dtype=jnp.int32)[None, None, :])
 
 
 def multiplier_grid(op: SpectralOp, shape, dtype=None):
@@ -361,6 +369,8 @@ def plan_spectral_op(
     mesh=None,
     *,
     op: SpectralOp,
+    direction: int = FORWARD,
+    real: bool = False,
     decomposition: str | None = None,
     executor: str = "xla",
     dtype: Any = None,
@@ -377,7 +387,27 @@ def plan_spectral_op(
     """Plan one fused spectral operator: FFT -> pointwise ``op`` ->
     iFFT as ONE jitted program, I/O in the chain's canonical input
     layout on BOTH sides (in == out sharding; a unit multiplier is the
-    identity, forward unnormalized x inverse 1/N).
+    identity, forward unnormalized x inverse 1/N). An operator runs its
+    forward transform first: ``direction`` is ``FORWARD`` or a
+    ``ValueError``.
+
+    ``real=True`` plans the operator on a real field (one device only;
+    a mesh raises ``ValueError``): ``dtype`` stays the complex working
+    dtype and the plan takes and returns its real counterpart (float32
+    for complex64). The program is the executor's r2c along axis 2
+    (``t0_r2c_z``), the forward complex axes 0 and 1 (``t3_fft_xy``),
+    the multiplier on the half-spectrum grid ``(n0, n1, n2//2 + 1)``
+    (``t_mid``), the inverse complex axes (``t3_ifft_xy``) and the c2r
+    back to ``n2`` with numpy's 1/N (``t0_c2r_z``): numpy's
+    ``irfftn(m_half * rfftn(x), s=shape)``. The half grid indexes axis
+    2 as ``0..n2//2``, with the Nyquist index of an even ``n2`` at its
+    signed frequency ``-n2/2``, as on the full grid. For a multiplier
+    with ``m(-k) = conj(m(k))`` (every even real one: poisson,
+    biharmonic, helmholtz, gaussian) the result is the complex plan's
+    on the same field. For an odd one such as ``gradient`` the
+    ``irfftn`` convention decides the Nyquist planes: the c2r keeps
+    only the real part of the DC and Nyquist planes of axis 2, so
+    ``gradient(2)`` contributes nothing at the Nyquist plane.
 
     The chain runs the canonical forward decomposition, stops at the
     transposed midpoint (slab: Y-slab layout after the t2 exchange;
@@ -396,6 +426,10 @@ def plan_spectral_op(
     planner under the operator's own wisdom kind (``op:<name>`` —
     transform winners never cross-replay; see ``docs/TUNING.md``).
     """
+    if direction != FORWARD:
+        raise ValueError(
+            f"an operator plan runs its forward transform first: direction "
+            f"must be FORWARD ({FORWARD}), got {direction!r}")
     shape, _ = _api._check_direction(shape, FORWARD)
     if isinstance(op, (list, tuple)):
         # Operator chaining: a sequence composes its diagonal
@@ -410,29 +444,66 @@ def plan_spectral_op(
     opts = _api._resolve_options(
         decomposition, executor, donate, algorithm, options,
         overlap_chunks, tune, wire_dtype, max_roundtrip_err, fuse=fuse)
+    cdtype = _api._default_cdtype(dtype)
+    real = bool(real)
+    if real:
+        if not jnp.issubdtype(cdtype, jnp.complexfloating):
+            raise ValueError(
+                f"real operator plans take the complex working dtype, got "
+                f"{cdtype}; the real field's dtype is derived from it")
+        if logic_plan3d(shape, mesh, opts, forward=True,
+                        batch=batch).decomposition != "single":
+            raise ValueError(
+                "real=True operator plans run on one device; on a mesh "
+                "plan the complex operator (real=False)")
     if resolve_tune_mode(opts.tune) != "off":
         return _tuned_op_plan(shape, mesh, op, opts,
-                              dict(dtype=dtype, batch=batch))
+                              dict(dtype=dtype, batch=batch, real=real))
     if opts.executor == "auto":
         import functools
 
         return _api._auto_plan(
             functools.partial(plan_spectral_op, shape, mesh), opts,
-            op=op, dtype=dtype, batch=batch)
-    cdtype = _api._default_cdtype(dtype)
+            op=op, dtype=dtype, batch=batch, real=real)
     lp = logic_plan3d(shape, mesh, opts, forward=True, batch=batch)
     lp = _dc_replace(lp, op=op.name)
     mult = _multiplier_fn(op, shape, cdtype)
     bo = 0 if batch is None else 1
+    io_dtype = cdtype
 
-    if lp.decomposition == "single":
+    if real:
+        ex = get_executor(opts.executor)
+        r2c, c2r = get_r2c(opts.executor), get_c2r(opts.executor)
+        n2 = shape[2]
+        grids = _full_grids(shape, n2 // 2 + 1)
+        cplx_axes = (bo, 1 + bo)
+
+        def _single_real(x):
+            with stage_scope("t0_r2c_z"):
+                y = r2c(x, 2 + bo)
+            with stage_scope("t3_fft_xy"):
+                y = ex(y, cplx_axes, True)
+            with stage_scope("t_mid"), stage_scope("t_mid_pointwise"):
+                y = apply_multiplier(y, mult(*grids))
+            with stage_scope("t3_ifft_xy"):
+                y = ex(y, cplx_axes, False)
+            with stage_scope("t0_c2r_z"):
+                return c2r(y, n2, 2 + bo)
+
+        # Real field and half spectrum differ in dtype and size, so no
+        # buffer can be donated (the plan_dft_r2c_3d policy).
+        fn = jax.jit(_single_real)
+        spec = None
+        io_dtype = jnp.dtype(jnp.float64 if cdtype == jnp.complex128
+                             else jnp.float32)
+    elif lp.decomposition == "single":
         ex = get_executor(opts.executor)
         fft_axes = tuple(a + bo for a in range(3))
         grids = _full_grids(shape)
 
         def _single(x):
             y = ex(x, fft_axes, True)
-            with stage_scope("t_mid_pointwise"):
+            with stage_scope("t_mid"), stage_scope("t_mid_pointwise"):
                 y = apply_multiplier(y, mult(*grids))
             return ex(y, fft_axes, False)
 
@@ -473,13 +544,16 @@ def plan_spectral_op(
         slab_axes=lp.slab_axes, pencil_perm=lp.pencil_perm,
         pencil_order=lp.pencil_order)[0][1])
     io_shape = shape if batch is None else (batch,) + shape
+    _metrics.inc("spectral_op_plans", op=op.name, real=real,
+                 decomposition=lp.decomposition)
     return OpPlan3D(
         shape=shape, direction=FORWARD, dtype=cdtype,
         decomposition=lp.decomposition, executor=opts.executor,
         mesh=lp.mesh, fn=fn, spec=spec,
         in_sharding=in_sh, out_sharding=in_sh,
         in_boxes=boxes, out_boxes=list(boxes),
-        in_shape=io_shape, out_shape=io_shape, batch=batch,
+        in_shape=io_shape, out_shape=io_shape,
+        in_dtype=io_dtype, out_dtype=io_dtype, real=real, batch=batch,
         options=lp.options, logic=lp,
         op=op.name, op_spec=op, multiplier=mult,
     )
@@ -491,7 +565,8 @@ plan_spectral_op = _api._plan_cached("op", plan_spectral_op)
 def solve_poisson(shape, mesh=None, **kw) -> OpPlan3D:
     """Fused Poisson solver plan: ``plan(f)`` returns the mean-free u
     with ``laplacian(u) = f - mean(f)`` on the unit torus (multiplier
-    ``-1/|k|^2``, zero mode nulled)."""
+    ``-1/|k|^2``, zero mode nulled). ``real=True`` solves for a real
+    field on the half spectrum (:func:`plan_spectral_op`)."""
     return plan_spectral_op(shape, mesh, op=poisson(), **kw)
 
 
@@ -547,7 +622,7 @@ def _tuned_op_plan(shape, mesh, op: SpectralOp, options, plan_kw: dict):
     dtype = _api._default_cdtype(plan_kw.get("dtype"))
     batch = plan_kw.get("batch")
     err_budget = options.max_roundtrip_err
-    kind = f"op:{op.name}"
+    kind = f"op:{op.name}" + (":real" if plan_kw.get("real") else "")
     key = tuner.wisdom_key(
         kind=kind, shape=shape, dtype=dtype, direction=FORWARD,
         ndev=ndev, mesh_dims=mesh_dims, batch=batch,

@@ -473,11 +473,13 @@ def plan_info(plan) -> str:
             lines.append(f"in sharding:  {plan.in_sharding.spec}")
             lines.append(f"out sharding: {plan.out_sharding.spec}")
         return "\n".join(lines)
+    _op = getattr(plan, "op", "")
+    _rc = plan.real and not _op  # a real operator plan is r2c AND c2r
     lines = [
         f"plan: {plan.in_shape} -> {plan.out_shape} "
         f"({'forward' if plan.forward else 'backward'}"
-        f"{', r2c' if plan.real and plan.forward else ''}"
-        f"{', c2r' if plan.real and not plan.forward else ''})",
+        f"{', r2c' if _rc and plan.forward else ''}"
+        f"{', c2r' if _rc and not plan.forward else ''})",
         f"decomposition: {plan.decomposition}",
         f"executor: {plan.executor}",
         f"algorithm: {plan.options.algorithm}",
@@ -493,8 +495,11 @@ def plan_info(plan) -> str:
         lines.append(
             f"batch: {_b} coalesced transforms (one shared exchange per "
             f"t2 stage; batch rides the collectives as a bystander dim)")
-    _op = getattr(plan, "op", "")
-    if _op:
+    if _op and plan.real:
+        lines.append(
+            f"operator: fused {_op} on a real field (r2c -> pointwise on "
+            f"the half spectrum -> c2r in one program)")
+    elif _op:
         lines.append(
             f"operator: fused {_op} (FFT -> pointwise -> iFFT in one "
             f"program; multiplier applied at the transposed t_mid "

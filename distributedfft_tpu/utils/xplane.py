@@ -135,16 +135,18 @@ def device_ops(path_or_bytes) -> dict[str, list[tuple[str, str, int, int]]]:
 
 
 def op_stage(tf_op: str) -> str | None:
-    """The ``t0``..``t3`` stage of a device op: that of the outermost
-    segment of its ``op_name`` path that :func:`.trace.stage_key` maps
-    to one (``cc<j>:`` prefixes, ``[k]`` chunk suffixes and the
-    ``t2a``/``t2b`` legs included); None for an op under no stage scope.
-    The profiler appends ``:<op type>`` to the path, which is dropped."""
+    """The ``t0``..``t3`` or ``t_mid`` stage of a device op: that of the
+    outermost segment of its ``op_name`` path that
+    :func:`.trace.stage_key` maps to one (``cc<j>:`` prefixes, ``[k]``
+    chunk suffixes and the ``t2a``/``t2b`` legs included; an operator
+    chain's ``t_mid_pointwise`` counts for the ``t_mid`` around it);
+    None for an op under no stage scope. The profiler appends
+    ``:<op type>`` to the path, which is dropped."""
     head, sep, last = tf_op.rpartition("/")
     if ":" in last and not stage_key(last):
         last = last.rpartition(":")[0]
     for seg in (head.split("/") if sep else []) + [last]:
         key = stage_key(seg)
-        if key in ("t0", "t1", "t2", "t3"):
+        if key is not None:
             return key
     return None

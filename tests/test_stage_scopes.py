@@ -83,6 +83,21 @@ def test_overlapped_plan_chunks_carry_scopes():
     assert {xplane.op_stage(n) for n in ops["all-to-all"]} == {"t2"}
 
 
+@needs_4
+def test_slab_operator_midpoint_maps_to_t_mid():
+    """A fused slab operator's midpoint (final forward X FFT, multiply,
+    first inverse X FFT) is attributed to ``t_mid``, apart from the
+    t0/t3 FFTs around it."""
+    shape = (16, 16, 16)
+    plan = dfft.solve_poisson(shape, dfft.make_mesh(4), dtype=jnp.complex64)
+    ops = _op_names(plan.fn, jnp.zeros(shape, jnp.complex64))
+    stages = [xplane.op_stage(n) for n in ops["fft"]]
+    # t0 YZ | t_mid forward X, inverse X | t3 inverse Y, inverse Z
+    assert sorted(stages) == ["t0", "t3", "t3", "t_mid", "t_mid"]
+    mults = [n for n in ops.get("multiply", []) if "t_mid_pointwise" in n]
+    assert mults and {xplane.op_stage(n) for n in mults} == {"t_mid"}
+
+
 def _slab_stages(shape):
     from distributedfft_tpu.parallel.slab import build_slab_stages
 
@@ -165,7 +180,15 @@ def test_stage_scope_names_the_ops():
     ("jit(<lambda>)/jit(fft):", None),
     ("x:", None),
     ("", None),
-    ("jit(fn)/t_mid[0]/t_mid_pointwise/mul:", None),
+    # an operator's multiply counts for the t_mid around it
+    ("jit(fn)/t_mid[0]/t_mid_pointwise/mul:", "t_mid"),
+    ("jit(_single_real)/t_mid/t_mid_pointwise/mul:", "t_mid"),
+    ("jit(fn)/t_mid_pointwise/mul:", None),
+    # a real operator's real-axis edges and complex axes
+    ("jit(_single_real)/t0_r2c_z/jit(fft)/fft:", "t0"),
+    ("jit(_single_real)/t0_c2r_z/reshape:", "t0"),
+    ("jit(_single_real)/t3_fft_xy/jit(fft)/fft:", "t3"),
+    ("jit(_single_real)/t3_ifft_xy/jit(fft)/fft:", "t3"),
 ])
 def test_op_stage_rule(tf_op, stage):
     assert xplane.op_stage(tf_op) == stage
