@@ -251,6 +251,7 @@ def phases_four_chips(s: Smoke, n: int, seed: int) -> None:
             y = fwd(x)
             r = bwd(y)
             out = {"decomposition": fwd.decomposition,
+                   "executor": [fwd.executor, bwd.executor],
                    "in_placement": placement(x),
                    "out_placement": placement(y),
                    "rel_l2": rel_l2(np.asarray(y), ref),

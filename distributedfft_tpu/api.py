@@ -59,6 +59,7 @@ from .plan_logic import (
     resolve_tune_mode,
     spec_entries as _spec_entries_impl,
 )
+from .parallel.exchange import overlap_chunk_bounds
 from .parallel.pencil import PencilSpec, build_pencil_fft3d, build_pencil_rfft3d
 from .parallel.slab import (
     SlabSpec,
@@ -214,30 +215,64 @@ def _default_executor(executor: str) -> str:
 
 
 def _on_tpu() -> bool:
-    """The backend predicate of :func:`_single_default_executor`."""
+    """The backend predicate of the default executor rules."""
     return jax.default_backend() == "tpu"
 
 
-def _single_default_executor(shape, dtype, batch) -> str:
-    """The executor a single-device c2c plan gets when the caller names
-    none: ``pallas`` on the TPU for an unbatched complex64 plan whose
-    every axis takes a plane pass (``pallas_fft.plane_pass_eligible``):
-    one complex64 split, three in-place passes, one combine. On a TPU
-    v5e it ran 1.2x (128^3) to 5x (384^3) faster than XLA's FFT in
-    both directions, on every grid tried (axes of 128 to 1024). ``xla``
-    everywhere else. Counted in ``c2c_default_pallas``. The plan runs
-    it at HIGHEST whatever ``DFFT_MM_PRECISION`` says
-    (``plan_dft_c2c_3d``)."""
+def _plane_default(shape, dtype, batch, blocks, decomposition) -> str:
+    """``pallas`` on the TPU for an unbatched complex64 c2c plan whose
+    every local FFT, a ``(block shape, axis)`` of ``blocks``, takes a
+    plane pass (``pallas_fft.plane_pass_eligible``); ``xla`` otherwise.
+    Counted in ``c2c_default_pallas``. The gates run before the Pallas
+    import, so plans that cannot take the kernels never pay it."""
     if (batch is not None or jnp.dtype(dtype) != jnp.complex64
             or not _on_tpu()):
         return "xla"
     from .ops import pallas_fft
 
-    if not all(pallas_fft.plane_pass_eligible(shape, ax)
-               for ax in range(len(shape))):
+    if not all(pallas_fft.plane_pass_eligible(b, ax) for b, ax in blocks):
         return "xla"
-    _metrics.inc("c2c_default_pallas", shape="x".join(map(str, shape)))
+    _metrics.inc("c2c_default_pallas", decomposition=decomposition,
+                 shape="x".join(map(str, shape)))
     return "pallas"
+
+
+def _single_default_executor(shape, dtype, batch) -> str:
+    """The executor a single-device c2c plan gets when the caller names
+    none: ``pallas`` on the TPU for an unbatched complex64 plan whose
+    every axis takes a plane pass: one complex64 split, three in-place
+    passes, one combine (:func:`_plane_default`). ``xla`` everywhere
+    else. The plan runs it at HIGHEST whatever ``DFFT_MM_PRECISION``
+    says (``plan_dft_c2c_3d``)."""
+    return _plane_default(shape, dtype, batch,
+                          [(shape, ax) for ax in range(len(shape))],
+                          "single")
+
+
+def _slab_default_executor(spec: SlabSpec, dtype, batch,
+                           overlap_chunks: int) -> str:
+    """The slab counterpart of :func:`_single_default_executor`, judged
+    on the chain's local blocks: ``pallas`` where t0's block (the
+    in-axis shard, the other two axes whole) takes a plane pass on both
+    of its axes and t3's block (the in-axis whole, the out-axis shard,
+    each of the ``overlap_chunks`` slices of the third axis) on the
+    in-axis. The chain then splits once, carries float32 planes through
+    t2 and combines once. ``xla`` for the hierarchical transport's
+    (dcn, ici) slab axis, whose legs were never run on the plane
+    passes, and everywhere :func:`_plane_default` says so."""
+    if isinstance(spec.axis_name, tuple):
+        return "xla"
+    i, o = spec.in_axis, spec.out_axis
+    c = 3 - i - o
+    t0 = list(spec.shape)
+    t0[i] = spec.in_padded_extent // spec.parts
+    blocks = [(tuple(t0), ax) for ax in range(3) if ax != i]
+    for lo, hi in overlap_chunk_bounds(spec.shape[c], overlap_chunks):
+        t3 = list(spec.shape)
+        t3[o] = spec.out_padded_extent // spec.parts
+        t3[c] = hi - lo
+        blocks.append((tuple(t3), i))
+    return _plane_default(spec.shape, dtype, batch, blocks, "slab")
 
 
 def _resolve_options(
@@ -592,10 +627,12 @@ def plan_dft_c2c_3d(
 ) -> Plan3D:
     """Create a distributed 3D complex-to-complex FFT plan.
 
-    ``executor=None`` (the default) is ``DFFT_EXECUTOR`` when set, else
-    ``xla``, except on a single device, where
-    :func:`_single_default_executor` picks ``pallas`` for the shapes its
-    plane passes take; ``plan.executor`` names the one resolved.
+    ``executor=None`` (the default) is ``DFFT_EXECUTOR`` when set, else,
+    on the TPU, ``pallas`` for an unbatched complex64 plan on one device
+    or a slab whose local blocks the plane passes take
+    (:func:`_single_default_executor`, :func:`_slab_default_executor`;
+    run at HIGHEST), and ``xla`` elsewhere, pencils included;
+    ``plan.executor`` names the one resolved.
 
     ``mesh`` may be a :class:`jax.sharding.Mesh` (1D -> slab, 2D -> pencil),
     an int (decomposition chosen by :func:`~.plan_logic.choose_decomposition`
@@ -704,18 +741,27 @@ def plan_dft_c2c_3d(
     if (in_spec is not None or out_spec is not None) and lp.mesh is None:
         raise ValueError("in_spec/out_spec require a mesh")
 
-    if lp.decomposition == "single":
-        label = opts.executor
-        if defaulted:
-            import dataclasses
+    label = opts.executor
+    # A default is still "xla" here unless the thunk guard substituted it;
+    # its substitute wins.
+    if (defaulted and opts.executor == "xla"
+            and lp.decomposition in ("single", "slab")):
+        import dataclasses
 
+        if lp.decomposition == "single":
             ex = _single_default_executor(shape, dtype, batch)
-            opts = dataclasses.replace(opts, executor=ex)
-            lp = dataclasses.replace(
-                lp, options=dataclasses.replace(lp.options, executor=ex))
-            # The default plan is exact, as XLA's FFT is: its pallas
-            # contractions run at HIGHEST, not at DFFT_MM_PRECISION.
-            label = "pallas:highest" if ex == "pallas" else ex
+        else:
+            ex = _slab_default_executor(
+                SlabSpec(shape, math.prod(lp.mesh.devices.shape),
+                         _slab_axis_name(lp.mesh), *lp.slab_axes),
+                dtype, batch, lp.options.overlap_chunks)
+        opts = dataclasses.replace(opts, executor=ex)
+        lp = dataclasses.replace(
+            lp, options=dataclasses.replace(lp.options, executor=ex))
+        # The default plan is exact, as XLA's FFT is: its pallas
+        # contractions run at HIGHEST, not at DFFT_MM_PRECISION.
+        label = "pallas:highest" if ex == "pallas" else ex
+    if lp.decomposition == "single":
         ex = get_executor(label)
         fft_axes = (0, 1, 2) if batch is None else (1, 2, 3)
         fn = jax.jit(lambda x: ex(x, fft_axes, forward))
@@ -723,7 +769,7 @@ def plan_dft_c2c_3d(
     elif lp.decomposition == "slab":
         fn, spec = build_slab_fft3d(
             lp.mesh, shape, axis_name=_slab_axis_name(lp.mesh),
-            executor=opts.executor, forward=forward, donate=opts.donate,
+            executor=label, forward=forward, donate=opts.donate,
             algorithm=opts.algorithm,
             in_axis=lp.slab_axes[0], out_axis=lp.slab_axes[1],
             overlap_chunks=lp.options.overlap_chunks, batch=batch,

@@ -258,10 +258,13 @@ def observe(name: str, value: float, **labels) -> None:
                     r[j] = value
 
 
-def counter_total(name: str) -> float:
-    """Sum of the counter ``name`` across every label combination."""
+def counter_total(name: str, **labels) -> float:
+    """Sum of the counter ``name`` across every label combination, or
+    across those that carry ``labels``."""
+    want = set(_key(name, labels)[1])
     with _lock:
-        return sum(v for (n, _), v in _counters.items() if n == name)
+        return sum(v for (n, ls), v in _counters.items()
+                   if n == name and want <= set(ls))
 
 
 def _label_str(labels: tuple) -> str:

@@ -1,4 +1,5 @@
-"""The plane-resident Pallas executor and the single-device default rule.
+"""The plane-resident Pallas executor and the single-device and slab default
+rules.
 
 A complex64 operand is split into float32 planes once, each axis runs one
 in-place plane pass (or, where the 128-wide factoring cannot take it, the
@@ -12,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax import lax
+from jax.sharding import Mesh
 
 import distributedfft_tpu as dfft
 from distributedfft_tpu import api
@@ -135,25 +137,72 @@ def test_default_rule_picks_pallas(on_tpu):
                      np.complex64)
 
 
+def _needs(n):
+    if len(jax.devices()) < n:
+        pytest.skip(f"needs {n} devices")
+
+
 @pytest.mark.parametrize("case", [
-    "explicit_xla", "slab_mesh", "complex128", "batched", "length"])
+    "explicit_xla", "complex128", "batched", "length", "pencil_mesh",
+    "slab_lanes96", "slab_t3_chunks", "slab_batched", "slab_hierarchical",
+    "slab_explicit_options"])
 def test_default_rule_keeps_xla(on_tpu, case):
     shape, mesh, kw = (128, 128, 128), None, {"dtype": jnp.complex64}
+    if case.startswith("slab"):
+        _needs(4)
+        mesh = dfft.make_mesh(4)
     if case == "explicit_xla":
         kw["executor"] = "xla"
-    elif case == "slab_mesh":
-        if len(jax.devices()) < 4:
-            pytest.skip("needs 4 devices")
-        mesh = dfft.make_mesh(4)
     elif case == "complex128":
         kw["dtype"] = jnp.complex128
-    elif case == "batched":
+    elif case in ("batched", "slab_batched"):
         kw["batch"] = 2
-    else:
+    elif case in ("length", "slab_lanes96"):
         shape = (128, 128, 96)
+    elif case == "pencil_mesh":
+        _needs(4)
+        mesh = dfft.make_mesh((2, 2))
+    elif case == "slab_t3_chunks":
+        # t0's [32, 128, 128] block takes both passes; t3's two overlap
+        # chunks are 64 lanes wide, which no major pass tiles.
+        kw["overlap_chunks"] = 2
+    elif case == "slab_hierarchical":
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("dcn", "ici"))
+        kw["algorithm"] = "hierarchical"
+    elif case == "slab_explicit_options":
+        kw["options"] = dfft.PlanOptions()
     plan = dfft.plan_dft_c2c_3d(shape, mesh, **kw)
+    assert plan.decomposition == ("pencil" if case == "pencil_mesh" else
+                                  "slab" if mesh is not None else "single")
     assert plan.executor == "xla"
     assert m.counter_total("c2c_default_pallas") == 0.0
+
+
+@pytest.mark.parametrize("case", ["slab_mesh", "slab_overlap_chunks"])
+def test_default_rule_picks_pallas_on_a_slab(on_tpu, tpu_routing, case):
+    """A slab whose every local block takes the plane passes runs them:
+    t0's block [n0/p, n1, n2] on axes 1 and 2, t3's [n0, n1/p, n2/K] on
+    axis 0 (the backward chain's blocks are the mirror image)."""
+    _needs(4)
+    shape, kw = (128, 128, 128), {}
+    if case == "slab_overlap_chunks":
+        shape, kw = (128, 128, 256), {"overlap_chunks": 2}
+    mesh = dfft.make_mesh(4)
+    x = _rand_c64(shape, seed=5)
+    for n, forward in enumerate((True, False), start=1):
+        plan = dfft.plan_dft_c2c_3d(
+            shape, mesh, dtype=jnp.complex64,
+            direction=dfft.FORWARD if forward else dfft.BACKWARD, **kw)
+        assert plan.decomposition == "slab"
+        assert plan.executor == "pallas"
+        assert plan.options.executor == "pallas"
+        assert m.counter_total("c2c_default_pallas",
+                               decomposition="slab") == n
+        explained = dfft.explain(plan, measure=False)["plan"]
+        assert explained["executor"] == "pallas"
+        y = np.asarray(plan(jax.device_put(x, plan.in_sharding)))
+        tu.assert_approx(y, _reference(x, (0, 1, 2), forward), np.complex64)
 
 
 def _dot_precisions(jaxpr):
@@ -189,6 +238,32 @@ def test_default_plan_stays_exact_under_a_lower_precision_env(
     tu.assert_approx(np.asarray(plan(x)), _reference(x, (0, 1, 2), True),
                      np.complex64)
     explicit = dfft.plan_dft_c2c_3d(shape, None, dtype=jnp.complex64,
+                                    executor="pallas")
+    found = _dot_precisions(jax.make_jaxpr(explicit.fn)(spec).jaxpr)
+    assert set(found) == {(lax.Precision.DEFAULT,) * 2}, found
+
+
+def test_default_slab_plan_stays_exact_under_a_lower_precision_env(
+        on_tpu, tpu_routing, monkeypatch):
+    """The slab twin of the test above: under ``DFFT_MM_PRECISION=default``
+    every contraction of the default slab plan runs at HIGHEST, while an
+    explicit ``pallas`` slab plan follows the env. (Inside ``shard_map``
+    the interpreter takes the per-axis matmul route, not the plane
+    passes, so the dots are counted per axis, not per pass.)"""
+    _needs(4)
+    monkeypatch.setenv("DFFT_MM_PRECISION", "default")
+    shape, mesh = (128, 128, 128), dfft.make_mesh(4)
+    plan = dfft.plan_dft_c2c_3d(shape, mesh, dtype=jnp.complex64)
+    assert plan.executor == "pallas"
+    spec = jax.ShapeDtypeStruct(shape, jnp.complex64,
+                                sharding=plan.in_sharding)
+    found = _dot_precisions(jax.make_jaxpr(plan.fn)(spec).jaxpr)
+    assert len(found) >= 3, found  # at least one per axis
+    assert set(found) == {(lax.Precision.HIGHEST,) * 2}, found
+    x = _rand_c64(shape, seed=6)
+    y = np.asarray(plan(jax.device_put(x, plan.in_sharding)))
+    tu.assert_approx(y, _reference(x, (0, 1, 2), True), np.complex64)
+    explicit = dfft.plan_dft_c2c_3d(shape, mesh, dtype=jnp.complex64,
                                     executor="pallas")
     found = _dot_precisions(jax.make_jaxpr(explicit.fn)(spec).jaxpr)
     assert set(found) == {(lax.Precision.DEFAULT,) * 2}, found

@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 import distributedfft_tpu as dfft
+from distributedfft_tpu import api
 from distributedfft_tpu.ops import dft_matmul, pallas_fft
 from distributedfft_tpu.ops.executors import get_executor
 from distributedfft_tpu.parallel.mesh import PENCIL_AXES, SLAB_AXIS
@@ -193,3 +194,36 @@ def test_distributed_512_compiles_on_four_chips(topo, mesh_shape):
     text = compiled.as_text()
     assert "all-to-all" in text
     _fits_one_chip(compiled)  # per-device bytes
+
+
+@pytest.mark.parametrize("forward", [True, False], ids=["fwd", "bwd"])
+def test_default_slab_plane_path_512_on_four_chips(topo, real_kernels,
+                                                   monkeypatch, forward):
+    """The slab default on the TPU (the planner is told it runs on one):
+    one complex64 split, the float32 planes through t2, three in-place
+    plane passes, one combine, no FFT op, and no more temporary memory
+    per chip than the ``xla`` plan, but for the passes' tables."""
+    monkeypatch.setattr(api, "_on_tpu", lambda: True)
+    dfft.clear_plan_cache()
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), (SLAB_AXIS,))
+    kw = dict(dtype=jnp.complex64,
+              direction=dfft.FORWARD if forward else dfft.BACKWARD)
+    try:
+        plan = dfft.plan_dft_c2c_3d((N,) * 3, mesh, **kw)
+        assert plan.executor == "pallas"
+        compiled = _compile_plan(plan, plan.in_sharding)
+        xla_plan = dfft.plan_dft_c2c_3d((N,) * 3, mesh, executor="xla", **kw)
+        xla_temp = _compile_plan(
+            xla_plan, xla_plan.in_sharding).memory_analysis()
+    finally:
+        dfft.clear_plan_cache()
+    text = compiled.as_text()
+    targets = re.findall(r'custom_call_target="([^"]+)"', text)
+    assert sorted(targets) == sorted(
+        ["X64SplitHigh", "X64SplitLow", "X64Combine"]
+        + ["tpu_custom_call"] * 3), targets
+    assert " fft(" not in text and "all-to-all" in text
+    # At 512^3 both plans hold 384 MiB of temp a chip; the passes' float32
+    # DFT and twiddle tables add tens of KiB (1024^3: 3.00 GiB against 4.00).
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= xla_temp.temp_size_in_bytes + 2**20, (temp, xla_temp)
