@@ -45,10 +45,6 @@ def main() -> int:
                     help="also time full 3D c2c at this cube size per executor")
     ap.add_argument("--strided", action="store_true",
                     help="also sweep the strided axis-0 kernel at --n")
-    ap.add_argument("--plane", type=int, default=None,
-                    help="also sweep the fused 2D kernel at this plane size")
-    ap.add_argument("--plane-batch", type=int, default=None)
-    ap.add_argument("--tiles2d", type=int, nargs="*", default=[1, 2, 4])
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
 
@@ -171,48 +167,6 @@ def main() -> int:
                       file=sys.stderr, flush=True)
         os.environ.pop("DFFT_PALLAS_TILE_STRIDED", None)
         pallas_fft._fft_strided_tiles.clear_cache()
-
-    if args.plane:
-        ny = nz = args.plane
-        pb = args.plane_batch or (4 if args.quick else max(1, args.plane))
-        xp = jax.jit(jax.lax.complex)(
-            jax.random.normal(k1, (pb, ny, nz), jnp.float32),
-            jax.random.normal(k2, (pb, ny, nz), jnp.float32))
-        sync(xp)
-        model2 = 5.0 * pb * ny * nz * math.log2(ny * nz)
-        xla2 = jax.jit(lambda a: jnp.fft.fftn(a, axes=(1, 2)))
-        y2_ref = None
-        try:
-            t = time_fn(xla2, xp)
-            y2_ref = xla2(xp)
-            sync(y2_ref)
-            rec.record("2d-xla", ny, pb, "-", f"{t:.6f}",
-                       f"{model2 / t / 1e9:.1f}", "0", "ok")
-            print(f"xla fft2 [{pb},{ny},{nz}]: {t*1e3:.3f} ms "
-                  f"({model2/t/1e9:.1f} GFlops)", flush=True)
-        except Exception as e:  # noqa: BLE001
-            print(f"xla fft2 failed: {e}", file=sys.stderr, flush=True)
-        for tile in args.tiles2d:
-            os.environ["DFFT_PALLAS_TILE2D"] = str(tile)
-            pallas_fft._fft2_tiles.clear_cache()
-            try:
-                pf2 = jax.jit(lambda a: pallas_fft.fft2_last(a, forward=True))
-                t = time_fn(pf2, xp)
-                err = (max_rel_err(pf2(xp), y2_ref)
-                       if y2_ref is not None else float("nan"))
-                rec.record("2d-pallas", ny, pb, tile, f"{t:.6f}",
-                           f"{model2 / t / 1e9:.1f}", f"{err:.3e}", "ok")
-                print(f"pallas2d tile={tile} [{pb},{ny},{nz}]: "
-                      f"{t*1e3:.3f} ms ({model2/t/1e9:.1f} GFlops) "
-                      f"err={err:.2e}", flush=True)
-            except Exception as e:  # noqa: BLE001
-                msg = " ".join(str(e).split())[:140]
-                rec.record("2d-pallas", ny, pb, tile, "-", "-", "-",
-                           f"error {msg}")
-                print(f"pallas2d tile={tile} failed: {msg}", file=sys.stderr,
-                      flush=True)
-        os.environ.pop("DFFT_PALLAS_TILE2D", None)
-        pallas_fft._fft2_tiles.clear_cache()
 
     if args.full3d:
         import distributedfft_tpu as dfft

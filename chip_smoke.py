@@ -51,7 +51,7 @@ FLAGSHIP = 512
 
 #: Counters every phase line reports; the first two must read zero.
 COUNTERS = ("pallas_fallback", "fusion_fallback", "pallas_pack_disabled",
-            "pallas_plane_per_axis", "serving_flushes", "serving_retries", "serving_degraded",
+            "serving_flushes", "serving_retries", "serving_degraded",
             "serving_isolated_failures")
 
 

@@ -54,12 +54,12 @@ from .plan_logic import (
     PlanOptions,
     io_boxes,
     logic_plan3d,
-    resolve_fuse,
+    resolve_executor,
+    resolve_options,
     resolve_overlap_chunks,
     resolve_tune_mode,
     spec_entries as _spec_entries_impl,
 )
-from .parallel.exchange import overlap_chunk_bounds
 from .parallel.pencil import PencilSpec, build_pencil_fft3d, build_pencil_rfft3d
 from .parallel.slab import (
     SlabSpec,
@@ -193,260 +193,6 @@ class OpPlan3D(Plan3D):
     op: str = ""
     op_spec: Any = None
     multiplier: Any = None
-
-
-def _executor_env() -> str:
-    """``DFFT_EXECUTOR``, or "" when unset or off."""
-    env = os.environ.get("DFFT_EXECUTOR", "").strip()
-    return "" if env in ("0", "none") else env
-
-
-def _default_executor(executor: str) -> str:
-    """Resolve the planner's executor default: ``DFFT_EXECUTOR`` (when
-    set) replaces the built-in ``"xla"`` default — the documented escape
-    hatch for environments whose XLA FFT lowering is broken (the
-    XLA:CPU fft-thunk fault: ``DFFT_EXECUTOR=matmul`` routes every
-    default-executor plan through the thunk-free MXU matmul engine). An
-    explicitly non-default ``executor=`` argument always wins; the knob
-    is part of the plan-cache key."""
-    if executor != "xla":
-        return executor
-    return _executor_env() or executor
-
-
-def _on_tpu() -> bool:
-    """The backend predicate of the default executor rules."""
-    return jax.default_backend() == "tpu"
-
-
-def _plane_default(shape, dtype, batch, blocks, decomposition) -> str:
-    """``pallas`` on the TPU for an unbatched complex64 c2c plan whose
-    every local FFT, a ``(block shape, axis)`` of ``blocks``, takes a
-    plane pass (``pallas_fft.plane_pass_eligible``); ``xla`` otherwise.
-    Counted in ``c2c_default_pallas``. The gates run before the Pallas
-    import, so plans that cannot take the kernels never pay it."""
-    if (batch is not None or jnp.dtype(dtype) != jnp.complex64
-            or not _on_tpu()):
-        return "xla"
-    from .ops import pallas_fft
-
-    if not all(pallas_fft.plane_pass_eligible(b, ax) for b, ax in blocks):
-        return "xla"
-    _metrics.inc("c2c_default_pallas", decomposition=decomposition,
-                 shape="x".join(map(str, shape)))
-    return "pallas"
-
-
-def _single_default_executor(shape, dtype, batch) -> str:
-    """The executor a single-device c2c plan gets when the caller names
-    none: ``pallas`` on the TPU for an unbatched complex64 plan whose
-    every axis takes a plane pass: one complex64 split, three in-place
-    passes, one combine (:func:`_plane_default`). ``xla`` everywhere
-    else. The plan runs it at HIGHEST whatever ``DFFT_MM_PRECISION``
-    says (``plan_dft_c2c_3d``)."""
-    return _plane_default(shape, dtype, batch,
-                          [(shape, ax) for ax in range(len(shape))],
-                          "single")
-
-
-def _slab_default_executor(spec: SlabSpec, dtype, batch,
-                           overlap_chunks: int) -> str:
-    """The slab counterpart of :func:`_single_default_executor`, judged
-    on the chain's local blocks: ``pallas`` where t0's block (the
-    in-axis shard, the other two axes whole) takes a plane pass on both
-    of its axes and t3's block (the in-axis whole, the out-axis shard,
-    each of the ``overlap_chunks`` slices of the third axis) on the
-    in-axis. The chain then splits once, carries float32 planes through
-    t2 and combines once. ``xla`` for the hierarchical transport's
-    (dcn, ici) slab axis, whose legs were never run on the plane
-    passes, and everywhere :func:`_plane_default` says so."""
-    if isinstance(spec.axis_name, tuple):
-        return "xla"
-    i, o = spec.in_axis, spec.out_axis
-    c = 3 - i - o
-    t0 = list(spec.shape)
-    t0[i] = spec.in_padded_extent // spec.parts
-    blocks = [(tuple(t0), ax) for ax in range(3) if ax != i]
-    for lo, hi in overlap_chunk_bounds(spec.shape[c], overlap_chunks):
-        t3 = list(spec.shape)
-        t3[o] = spec.out_padded_extent // spec.parts
-        t3[c] = hi - lo
-        blocks.append((tuple(t3), i))
-    return _plane_default(spec.shape, dtype, batch, blocks, "slab")
-
-
-def _resolve_options(
-    decomposition: str | None,
-    executor: str,
-    donate: bool,
-    algorithm: str,
-    options: PlanOptions | None,
-    overlap_chunks: int | str | None = None,
-    tune: str | None = None,
-    wire_dtype: str | None = None,
-    max_roundtrip_err: float | None = None,
-    mm_precision: str | None = None,
-    mm_complex: str | None = None,
-    fuse: bool | str | None = None,
-) -> PlanOptions:
-    if options is not None:
-        if (decomposition is not None or executor != "xla" or donate
-                or algorithm != "alltoall" or overlap_chunks is not None
-                or tune is not None or wire_dtype is not None
-                or max_roundtrip_err is not None
-                or mm_precision is not None or mm_complex is not None
-                or fuse is not None):
-            raise ValueError(
-                "pass either options= or individual plan keywords, not both"
-            )
-        return _apply_fuse(_apply_mm_tiers(options))
-    return _apply_fuse(_apply_mm_tiers(PlanOptions(
-        decomposition=decomposition or "auto",
-        algorithm=algorithm,
-        executor=_default_executor(executor),
-        donate=donate,
-        overlap_chunks=overlap_chunks,
-        tune=tune,
-        wire_dtype=wire_dtype,
-        max_roundtrip_err=max_roundtrip_err,
-        mm_precision=mm_precision,
-        mm_complex=mm_complex,
-        fuse=fuse,
-    )))
-
-
-def _apply_mm_tiers(opts: PlanOptions) -> PlanOptions:
-    """Normalize a plan's accuracy tier into its canonical executor
-    label: ``mm_precision``/``mm_complex`` compose into the executor
-    name (``matmul`` + ``bf16`` -> ``matmul:bf16``), and a label that
-    already carries suffixes back-fills the option fields — after this,
-    ``opts.executor`` and ``opts.mm_*`` are two views of one choice (the
-    label is what the plan cache, wisdom store, and benchmark stamps
-    key; the fields are what drivers read). ``mm_precision=None`` with a
-    bare executor is returned unchanged — byte-identical planning."""
-    import dataclasses
-
-    from .ops.executors import (
-        MM_EXECUTOR_BASES, fused_name, split_executor, split_fuse,
-        tiered_name,
-    )
-
-    ex = opts.executor
-    if opts.mm_precision is None and opts.mm_complex is None:
-        if ":" not in ex:
-            return opts
-        base, tier, cmode = split_executor(ex)  # validates the label
-        _, want_fuse = split_fuse(ex)  # the orthogonal fusion flag
-        return dataclasses.replace(
-            opts, mm_precision=tier, mm_complex=cmode,
-            # Canonical spelling ("matmul:high" -> "matmul:f32", the
-            # ":fuse" flag last): one label per tier across cache keys,
-            # wisdom, and stamps.
-            executor=fused_name(tiered_name(base, tier, cmode),
-                                want_fuse or None))
-    if not ex.split(":", 1)[0].startswith(MM_EXECUTOR_BASES):
-        if resolve_tune_mode(opts.tune) != "off":
-            # Tuned planning: the tier choice pins the TUNER's precision
-            # axis (every matmul-family candidate carries it) — the base
-            # executor here is just the search's starting point, not
-            # what runs.
-            return opts
-        raise ValueError(
-            f"mm_precision/mm_complex scope the matmul-family executors "
-            f"{MM_EXECUTOR_BASES}; executor={ex!r} never consults them "
-            f"(use tune='measure'/'wisdom' to search the tiered "
-            f"candidate axis instead)")
-    name = tiered_name(ex, opts.mm_precision, opts.mm_complex)
-    base, tier, cmode = (split_executor(name) if ":" in name
-                         else (name, None, None))
-    return dataclasses.replace(opts, executor=name, mm_precision=tier,
-                               mm_complex=cmode)
-
-
-def _apply_fuse(opts: PlanOptions) -> PlanOptions:
-    """Normalize the Pallas fusion flag into the canonical executor
-    label — the ``_apply_mm_tiers`` convention: after this,
-    ``opts.executor``'s ``:fuse`` flag and ``opts.fuse`` are two views
-    of one choice (the label is what the plan cache, wisdom store, and
-    benchmark stamps key; whether fusion actually *activates* is then
-    the stage-graph gate, :func:`..stagegraph.plan_fusion`).
-
-    An explicit ``fuse=True`` on an executor family without a fusion
-    tier is a loud error (the ``mm_precision`` discipline); the
-    ``DFFT_FUSE`` env default is a preference and is ignored there —
-    a global ``DFFT_FUSE=1`` must not break ``xla`` plans."""
-    import dataclasses
-
-    from .ops.executors import FUSE_BASES, fused_name, split_fuse
-
-    ex = opts.executor
-    if not isinstance(ex, str):
-        return opts
-    pinned = split_fuse(ex)[1] if ":" in ex else False
-    if opts.fuse is False and pinned:
-        raise ValueError(
-            f"executor {ex!r} already pins the fuse flag; fuse=False "
-            f"conflicts (drop one of the two spellings)")
-    want = resolve_fuse(opts.fuse)
-    if want and not pinned:
-        if ex.split(":", 1)[0] in FUSE_BASES:
-            ex = fused_name(ex, True)
-            pinned = True
-        elif opts.fuse is not None:
-            raise ValueError(
-                f"fuse=True scopes the Pallas-family executors "
-                f"{FUSE_BASES}; executor={ex!r} has no fusion tier "
-                f"(the DFFT_FUSE env default is ignored there)")
-    if ex == opts.executor and bool(opts.fuse) == pinned:
-        return opts
-    return dataclasses.replace(opts, executor=ex, fuse=pinned)
-
-
-def _thunk_guard_executor(opts: PlanOptions, lp: LogicPlan,
-                          forward: bool) -> str:
-    """The XLA:CPU fft-thunk retirement path at the planner level
-    (:func:`..ops.executors.thunk_guard_substitute` is the shared
-    predicate — the staged pipeline builders apply the same rule): with
-    ``DFFT_THUNK_GUARD`` armed, the known-poisoned class (inverse pencil
-    chains with uneven ceil-padded shards on the CPU backend) routes
-    through the substitute executor; everything else (and every plan
-    when the knob is unset — the default) keeps its executor untouched,
-    HLO-identical. Part of the plan-cache key."""
-    from .ops.executors import thunk_guard_substitute
-
-    if lp.mesh is None:
-        return opts.executor
-    # Uneven = some chain stage ceil-pads (shards of unequal shape); the
-    # even pencil chains run the thunk cleanly. The slab class is the
-    # MINOR-AXIS starved chain only: input slabs on axis 2 with
-    # zero-extent shards (extent < parts) — merely-starved chains on the
-    # major axes run the thunk fine, and substituting there would break
-    # the executor-sensitive bitwise-parity contracts for no protection.
-    uneven = any(len({b.shape for b in boxes}) > 1
-                 for _axes, boxes in lp.stages)
-    starved = bool(
-        lp.decomposition == "slab" and lp.slab_axes
-        and lp.slab_axes[0] == 2
-        and any(0 in b.shape for b in lp.stages[0][1]))
-    return thunk_guard_substitute(
-        opts.executor, decomposition=lp.decomposition, forward=forward,
-        uneven=uneven, starved=starved)
-
-
-def _guarded(opts: PlanOptions, lp: LogicPlan, forward: bool):
-    """Apply :func:`_thunk_guard_executor`; on a substitution, rewrite
-    both option views (the planner's and the logic skeleton's) so every
-    consumer — builders, metrics labels, bench stamps — describes the
-    executor that actually runs."""
-    import dataclasses
-
-    gex = _thunk_guard_executor(opts, lp, forward)
-    if gex == opts.executor:
-        return opts, lp
-    opts = dataclasses.replace(opts, executor=gex)
-    lp = dataclasses.replace(
-        lp, options=dataclasses.replace(lp.options, executor=gex))
-    return opts, lp
 
 
 def _check_direction(shape, direction) -> tuple[tuple[int, int, int], bool]:
@@ -603,6 +349,59 @@ def _wrap_user_layout(
     return wrapped, user_in, user_out
 
 
+def prepare_plan(planner: Callable, shape, mesh, executor: str | None,
+                 options: PlanOptions | None, knobs: dict, plan_kw: dict,
+                 *, forward: bool = True, transform: str = "c2c",
+                 op: str | None = None, tuned: Callable | None = None,
+                 layouts: tuple = (None, None)):
+    """The planners' shared preamble: resolve the options
+    (:func:`.plan_logic.resolve_options`), hand a tuned or
+    ``executor="auto"`` request to its measured selector, build the
+    logic plan, and resolve the executor
+    (:func:`.plan_logic.resolve_executor`).
+
+    ``planner`` is the public planner itself, re-entered by the
+    selectors with ``options=`` and ``plan_kw``; ``knobs`` are its
+    option keywords. ``transform`` is ``"c2c"`` or ``"r2c"``, ``op`` an
+    operator plan's op name (set on the logic plan), ``tuned`` the
+    operator planners' own tuned tier (``tuned(opts, plan_kw)``; the
+    transforms use :func:`.tuner.tuned_plan`), and ``layouts`` the
+    ``(in_spec, out_spec)`` the logic plan may absorb. Returns the
+    selectors' finished plan, else ``(lp, label)``: ``lp.options``
+    names the executor the plan reports, ``label`` the one its builders
+    run."""
+    import dataclasses
+
+    opts, named = resolve_options(executor, options, **knobs)
+    if resolve_tune_mode(opts.tune) != "off":
+        if tuned is None:
+            from . import tuner
+
+            return tuner.tuned_plan(transform, shape, mesh, opts, plan_kw)
+        return tuned(opts, plan_kw)
+    if opts.executor == "auto":
+        return _auto_plan(functools.partial(planner, shape, mesh), opts,
+                          **plan_kw)
+    if transform == "r2c" and opts.algorithm == "hierarchical":
+        raise ValueError(
+            "hierarchical transport supports the c2c chains; r2c/c2r "
+            "plans run the flat transports")
+    lp = logic_plan3d(shape, mesh, opts, forward=forward,
+                      in_spec=layouts[0], out_spec=layouts[1],
+                      batch=plan_kw.get("batch"))
+    if layouts != (None, None) and lp.mesh is None:
+        raise ValueError("in_spec/out_spec require a mesh")
+    if op is not None:
+        lp = dataclasses.replace(lp, op=op)
+    name, label = resolve_executor(
+        opts.executor if named else None, lp, transform=transform,
+        dtype=_default_cdtype(plan_kw.get("dtype")))
+    if name != lp.options.executor:
+        lp = dataclasses.replace(
+            lp, options=dataclasses.replace(lp.options, executor=name))
+    return lp, label
+
+
 def plan_dft_c2c_3d(
     shape: Sequence[int],
     mesh: Mesh | int | None = None,
@@ -629,10 +428,10 @@ def plan_dft_c2c_3d(
 
     ``executor=None`` (the default) is ``DFFT_EXECUTOR`` when set, else,
     on the TPU, ``pallas`` for an unbatched complex64 plan on one device
-    or a slab whose local blocks the plane passes take
-    (:func:`_single_default_executor`, :func:`_slab_default_executor`;
-    run at HIGHEST), and ``xla`` elsewhere, pencils included;
-    ``plan.executor`` names the one resolved.
+    or a slab whose local blocks the plane passes take (run at HIGHEST),
+    and ``xla`` elsewhere, pencils included
+    (:func:`.plan_logic.resolve_executor`); ``plan.executor`` names the
+    one resolved.
 
     ``mesh`` may be a :class:`jax.sharding.Mesh` (1D -> slab, 2D -> pencil),
     an int (decomposition chosen by :func:`~.plan_logic.choose_decomposition`
@@ -713,54 +512,22 @@ def plan_dft_c2c_3d(
     if batch is not None and (in_spec is not None or out_spec is not None):
         raise ValueError("batched plans take the canonical chain layouts; "
                          "in_spec/out_spec require batch=None (or 1)")
-    defaulted = executor is None and options is None and not _executor_env()
-    opts = _resolve_options(decomposition, executor or "xla", donate,
-                            algorithm, options, overlap_chunks, tune,
-                            wire_dtype, max_roundtrip_err, mm_precision,
-                            mm_complex, fuse)
-    if resolve_tune_mode(opts.tune) != "off":
-        from . import tuner
-
-        return tuner.tuned_plan(
-            "c2c", shape, mesh, opts,
-            dict(direction=direction, dtype=dtype, in_spec=in_spec,
-                 out_spec=out_spec, batch=batch))
-    if opts.executor == "auto":
-        return _auto_plan(
-            functools.partial(plan_dft_c2c_3d, shape, mesh), opts,
-            direction=direction, dtype=dtype, in_spec=in_spec,
-            out_spec=out_spec, batch=batch,
-        )
+    resolved = prepare_plan(
+        plan_dft_c2c_3d, shape, mesh, executor, options,
+        dict(decomposition=decomposition, algorithm=algorithm,
+             donate=donate, overlap_chunks=overlap_chunks, tune=tune,
+             wire_dtype=wire_dtype, max_roundtrip_err=max_roundtrip_err,
+             mm_precision=mm_precision, mm_complex=mm_complex, fuse=fuse),
+        dict(direction=direction, dtype=dtype, in_spec=in_spec,
+             out_spec=out_spec, batch=batch),
+        forward=forward, layouts=(in_spec, out_spec))
+    if isinstance(resolved, Plan3D):
+        return resolved
+    lp, label = resolved
+    opts = lp.options
     dtype = _default_cdtype(dtype)
-    lp = logic_plan3d(
-        shape, mesh, opts, forward=forward, in_spec=in_spec,
-        out_spec=out_spec, batch=batch,
-    )
-    opts, lp = _guarded(opts, lp, forward)
     world = world_box(shape)
-    if (in_spec is not None or out_spec is not None) and lp.mesh is None:
-        raise ValueError("in_spec/out_spec require a mesh")
 
-    label = opts.executor
-    # A default is still "xla" here unless the thunk guard substituted it;
-    # its substitute wins.
-    if (defaulted and opts.executor == "xla"
-            and lp.decomposition in ("single", "slab")):
-        import dataclasses
-
-        if lp.decomposition == "single":
-            ex = _single_default_executor(shape, dtype, batch)
-        else:
-            ex = _slab_default_executor(
-                SlabSpec(shape, math.prod(lp.mesh.devices.shape),
-                         _slab_axis_name(lp.mesh), *lp.slab_axes),
-                dtype, batch, lp.options.overlap_chunks)
-        opts = dataclasses.replace(opts, executor=ex)
-        lp = dataclasses.replace(
-            lp, options=dataclasses.replace(lp.options, executor=ex))
-        # The default plan is exact, as XLA's FFT is: its pallas
-        # contractions run at HIGHEST, not at DFFT_MM_PRECISION.
-        label = "pallas:highest" if ex == "pallas" else ex
     if lp.decomposition == "single":
         ex = get_executor(label)
         fft_axes = (0, 1, 2) if batch is None else (1, 2, 3)
@@ -772,18 +539,18 @@ def plan_dft_c2c_3d(
             executor=label, forward=forward, donate=opts.donate,
             algorithm=opts.algorithm,
             in_axis=lp.slab_axes[0], out_axis=lp.slab_axes[1],
-            overlap_chunks=lp.options.overlap_chunks, batch=batch,
-            wire_dtype=lp.options.wire_dtype,
+            overlap_chunks=opts.overlap_chunks, batch=batch,
+            wire_dtype=opts.wire_dtype,
         )
     else:
         row, col = lp.mesh.axis_names[:2]
         fn, spec = build_pencil_fft3d(
             lp.mesh, shape, row_axis=row, col_axis=col,
-            executor=opts.executor, forward=forward, donate=opts.donate,
+            executor=label, forward=forward, donate=opts.donate,
             algorithm=opts.algorithm,
             perm=lp.pencil_perm, order=lp.pencil_order,
-            overlap_chunks=lp.options.overlap_chunks, batch=batch,
-            wire_dtype=lp.options.wire_dtype,
+            overlap_chunks=opts.overlap_chunks, batch=batch,
+            wire_dtype=opts.wire_dtype,
         )
 
     in_sh, out_sh = _shardings(lp, spec, batch)
@@ -1166,7 +933,7 @@ def plan_dft_r2c_3d(
     *,
     direction: int = FORWARD,
     decomposition: str | None = None,
-    executor: str = "xla",
+    executor: str | None = None,
     dtype: Any = None,
     donate: bool = False,
     algorithm: str = "alltoall",
@@ -1223,18 +990,19 @@ def plan_dft_r2c_3d(
         raise ValueError("batched plans take the canonical chain layouts; "
                          "in_spec/out_spec require batch=None (or 1)")
     shape, forward = _check_direction(shape, direction)
-    opts = _resolve_options(decomposition, executor, donate, algorithm,
-                            options, overlap_chunks, tune, wire_dtype,
-                            max_roundtrip_err, mm_precision, mm_complex,
-                            fuse)
-    if resolve_tune_mode(opts.tune) != "off":
-        from . import tuner
-
-        return tuner.tuned_plan(
-            "r2c", shape, mesh, opts,
-            dict(direction=direction, dtype=dtype, in_spec=in_spec,
-                 out_spec=out_spec, batch=batch))
-    if opts.donate:
+    resolved = prepare_plan(
+        plan_dft_r2c_3d, shape, mesh, executor, options,
+        dict(decomposition=decomposition, algorithm=algorithm,
+             donate=donate, overlap_chunks=overlap_chunks, tune=tune,
+             wire_dtype=wire_dtype, max_roundtrip_err=max_roundtrip_err,
+             mm_precision=mm_precision, mm_complex=mm_complex, fuse=fuse),
+        dict(direction=direction, dtype=dtype, in_spec=in_spec,
+             out_spec=out_spec, batch=batch),
+        forward=forward, transform="r2c")
+    if isinstance(resolved, Plan3D):
+        return resolved
+    lp, label = resolved
+    if lp.options.donate:
         # r2c/c2r buffers can never alias (real world vs complex
         # half-spectrum differ in dtype and size), so donation would
         # only emit unusable-donation warnings per execute and skew the
@@ -1242,17 +1010,9 @@ def plan_dft_r2c_3d(
         # documented no-op (same policy as the dd tier).
         import dataclasses
 
-        opts = dataclasses.replace(opts, donate=False)
-    if opts.executor == "auto":
-        return _auto_plan(
-            functools.partial(plan_dft_r2c_3d, shape, mesh), opts,
-            direction=direction, dtype=dtype, in_spec=in_spec,
-            out_spec=out_spec, batch=batch,
-        )
-    if opts.algorithm == "hierarchical":
-        raise ValueError(
-            "hierarchical transport supports the c2c chains; r2c/c2r "
-            "plans run the flat transports")
+        lp = dataclasses.replace(
+            lp, options=dataclasses.replace(lp.options, donate=False))
+    opts = lp.options
     dtype = _default_cdtype(dtype)
     if not jnp.issubdtype(dtype, jnp.complexfloating):
         raise ValueError(
@@ -1265,14 +1025,12 @@ def plan_dft_r2c_3d(
     # r2c chains keep the canonical axis assignment (the real axis must be
     # axis 2, device-local on the real side); user layouts go through edge
     # reshards below rather than chain re-axing.
-    lp = logic_plan3d(shape, mesh, opts, forward=forward, batch=batch)
-    opts, lp = _guarded(opts, lp, forward)
     world, cworld = world_box(shape), world_box(cshape)
     bo = 0 if batch is None else 1
 
     if lp.decomposition == "single":
-        ex = get_executor(opts.executor)
-        r2c, c2r = get_r2c(opts.executor), get_c2r(opts.executor)
+        ex = get_executor(label)
+        r2c, c2r = get_r2c(label), get_c2r(label)
         if forward:
             fn = jax.jit(lambda x: ex(r2c(x, 2 + bo), (bo, 1 + bo), True))
         else:
@@ -1282,19 +1040,19 @@ def plan_dft_r2c_3d(
     elif lp.decomposition == "slab":
         fn, spec = build_slab_rfft3d(
             lp.mesh, shape, axis_name=lp.mesh.axis_names[0],
-            executor=opts.executor, forward=forward, donate=opts.donate,
+            executor=label, forward=forward, donate=opts.donate,
             algorithm=opts.algorithm,
-            overlap_chunks=lp.options.overlap_chunks, batch=batch,
-            wire_dtype=lp.options.wire_dtype,
+            overlap_chunks=opts.overlap_chunks, batch=batch,
+            wire_dtype=opts.wire_dtype,
         )
     else:
         row, col = lp.mesh.axis_names[:2]
         fn, spec = build_pencil_rfft3d(
             lp.mesh, shape, row_axis=row, col_axis=col,
-            executor=opts.executor, forward=forward, donate=opts.donate,
+            executor=label, forward=forward, donate=opts.donate,
             algorithm=opts.algorithm,
-            overlap_chunks=lp.options.overlap_chunks, batch=batch,
-            wire_dtype=lp.options.wire_dtype,
+            overlap_chunks=opts.overlap_chunks, batch=batch,
+            wire_dtype=opts.wire_dtype,
         )
 
     if (in_spec is not None or out_spec is not None) and lp.mesh is None:
@@ -1820,7 +1578,7 @@ _PLAN_ENV_KNOBS = (
     "DFFT_AUTO_EXECUTORS", "DFFT_MM_PRECISION", "DFFT_MM_COMPLEX",
     "DFFT_MM_SPLIT", "DFFT_MM_DIRECT_MAX", "DFFT_DD_DEPTH",
     "DFFT_PALLAS_PACK", "DFFT_PALLAS_SPLIT", "DFFT_PALLAS_TILE",
-    "DFFT_PALLAS_TILE2D", "DFFT_PALLAS_TILE_STRIDED", "DFFT_XLA_REAL",
+    "DFFT_PALLAS_TILE_STRIDED", "DFFT_XLA_REAL",
     "DFFT_FORCE_REAL_LOWERING", "DFFT_OVERLAP",
     # Executor routing: the default-executor escape hatch and the
     # XLA:CPU fft-thunk guard both change which executor a default

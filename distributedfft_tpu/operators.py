@@ -51,6 +51,7 @@ See ``docs/OPERATORS.md`` for the operator menu and the fusion model.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import math
 from dataclasses import dataclass, field, replace as _dc_replace
@@ -62,12 +63,14 @@ import jax
 import jax.numpy as jnp
 
 from . import api as _api
-from .api import FORWARD, OpPlan3D
+from .api import FORWARD, OpPlan3D, prepare_plan
 from .geometry import world_box
 from .ops.executors import get_c2r, get_executor, get_r2c
 from .parallel.pencil import build_pencil_spectral_op
 from .parallel.slab import apply_multiplier, build_slab_spectral_op
-from .plan_logic import logic_plan3d, resolve_tune_mode, stage_layouts
+from .plan_logic import (
+    logic_plan3d, resolve_options, resolve_tune_mode, stage_layouts,
+)
 from .utils import metrics as _metrics
 from .utils.trace import stage_scope
 
@@ -372,7 +375,7 @@ def plan_spectral_op(
     direction: int = FORWARD,
     real: bool = False,
     decomposition: str | None = None,
-    executor: str = "xla",
+    executor: str | None = None,
     dtype: Any = None,
     donate: bool = False,
     algorithm: str = "alltoall",
@@ -441,39 +444,39 @@ def plan_spectral_op(
             f"op must be a SpectralOp (poisson(), gradient(), ...) or "
             f"a sequence of them (operator chaining); got {op!r}")
     batch = _api._norm_batch(batch)
-    opts = _api._resolve_options(
-        decomposition, executor, donate, algorithm, options,
-        overlap_chunks, tune, wire_dtype, max_roundtrip_err, fuse=fuse)
+    knobs = dict(decomposition=decomposition, algorithm=algorithm,
+                 donate=donate, overlap_chunks=overlap_chunks, tune=tune,
+                 wire_dtype=wire_dtype, max_roundtrip_err=max_roundtrip_err,
+                 fuse=fuse)
     cdtype = _api._default_cdtype(dtype)
     real = bool(real)
     if real:
+        check, _ = resolve_options(executor, options, **knobs)
         if not jnp.issubdtype(cdtype, jnp.complexfloating):
             raise ValueError(
                 f"real operator plans take the complex working dtype, got "
                 f"{cdtype}; the real field's dtype is derived from it")
-        if logic_plan3d(shape, mesh, opts, forward=True,
+        if logic_plan3d(shape, mesh, check, forward=True,
                         batch=batch).decomposition != "single":
             raise ValueError(
                 "real=True operator plans run on one device; on a mesh "
                 "plan the complex operator (real=False)")
-    if resolve_tune_mode(opts.tune) != "off":
-        return _tuned_op_plan(shape, mesh, op, opts,
-                              dict(dtype=dtype, batch=batch, real=real))
-    if opts.executor == "auto":
-        import functools
-
-        return _api._auto_plan(
-            functools.partial(plan_spectral_op, shape, mesh), opts,
-            op=op, dtype=dtype, batch=batch, real=real)
-    lp = logic_plan3d(shape, mesh, opts, forward=True, batch=batch)
-    lp = _dc_replace(lp, op=op.name)
+    plan_kw = dict(dtype=dtype, batch=batch, real=real)
+    resolved = prepare_plan(
+        functools.partial(plan_spectral_op, op=op), shape, mesh, executor,
+        options, knobs, plan_kw, transform="r2c" if real else "c2c",
+        op=op.name, tuned=functools.partial(_tuned_op_plan, shape, mesh, op))
+    if isinstance(resolved, OpPlan3D):
+        return resolved
+    lp, label = resolved
+    opts = lp.options
     mult = _multiplier_fn(op, shape, cdtype)
     bo = 0 if batch is None else 1
     io_dtype = cdtype
 
     if real:
-        ex = get_executor(opts.executor)
-        r2c, c2r = get_r2c(opts.executor), get_c2r(opts.executor)
+        ex = get_executor(label)
+        r2c, c2r = get_r2c(label), get_c2r(label)
         n2 = shape[2]
         grids = _full_grids(shape, n2 // 2 + 1)
         cplx_axes = (bo, 1 + bo)
@@ -497,7 +500,7 @@ def plan_spectral_op(
         io_dtype = jnp.dtype(jnp.float64 if cdtype == jnp.complex128
                              else jnp.float32)
     elif lp.decomposition == "single":
-        ex = get_executor(opts.executor)
+        ex = get_executor(label)
         fft_axes = tuple(a + bo for a in range(3))
         grids = _full_grids(shape)
 
@@ -513,18 +516,18 @@ def plan_spectral_op(
         fn, spec = build_slab_spectral_op(
             lp.mesh, shape, mult,
             axis_name=_api._slab_axis_name(lp.mesh),
-            executor=opts.executor, donate=opts.donate,
+            executor=label, donate=opts.donate,
             algorithm=opts.algorithm,
-            overlap_chunks=lp.options.overlap_chunks, batch=batch,
-            wire_dtype=lp.options.wire_dtype)
+            overlap_chunks=opts.overlap_chunks, batch=batch,
+            wire_dtype=opts.wire_dtype)
     else:
         row, col = lp.mesh.axis_names[:2]
         fn, spec = build_pencil_spectral_op(
             lp.mesh, shape, mult, row_axis=row, col_axis=col,
-            executor=opts.executor, donate=opts.donate,
+            executor=label, donate=opts.donate,
             algorithm=opts.algorithm,
-            overlap_chunks=lp.options.overlap_chunks, batch=batch,
-            wire_dtype=lp.options.wire_dtype)
+            overlap_chunks=opts.overlap_chunks, batch=batch,
+            wire_dtype=opts.wire_dtype)
 
     # I/O sharding and boxes are the chain's INPUT side on both ends —
     # the operator's whole point is that the caller's layout round trip

@@ -17,11 +17,15 @@ any of them can be dropped into the distributed pipeline under ``jit`` /
   templateFFT's runtime-generated Stockham kernels.
 - ``"pallas"`` — the Pallas kernels
   (:mod:`distributedfft_tpu.ops.pallas_fft`): a complex64 operand is split
-  into float32 planes once, each axis is one in-place pass staged through
-  VMEM (one HBM read/write per axis), and the planes are combined once;
-  falls back to ``"matmul"`` for ineligible lengths/dtypes. The
-  single-device c2c planner's default on the TPU where every axis takes
-  a plane pass (``api._single_default_executor``).
+  into float32 planes once, each axis is one in-place plane pass staged
+  through VMEM (one HBM read/write per axis), and the planes are combined
+  once (``pallas_fft.fft_planes``). Axes the plane passes cannot take,
+  and other dtypes, run the balanced-split kernels, or the ``"matmul"``
+  fallback where those cannot.
+
+Which executor a plan runs is decided once, at plan time, by
+:func:`..plan_logic.resolve_executor`; the label grammar below (tiers,
+the fuse flag) is normalized by :func:`normalize_label`.
 """
 
 from __future__ import annotations
@@ -211,6 +215,71 @@ def tiered_name(base: str, precision: str | None = None,
     return fused_name(name, have_fuse) if have_fuse else name
 
 
+def normalize_label(opts):
+    """Compose a plan's accuracy tier and fusion flag into its canonical
+    executor label: ``mm_precision``/``mm_complex`` and ``fuse`` join the
+    executor name (``matmul`` + ``bf16`` -> ``matmul:bf16``, ``pallas``
+    + ``fuse=True`` -> ``pallas:fuse``), and a label that already carries
+    them fills the option fields back in. After this, ``opts.executor``
+    and the fields are two views of one choice: the label is what the
+    plan cache, the wisdom store and benchmark stamps key, the fields
+    what drivers read. Options with neither come back unchanged.
+
+    A tier on a family that never reads it raises unless the plan is
+    tuned (the tier then pins the tuner's precision axis, and the base
+    executor is only the search's starting point). ``fuse=True`` on a
+    family without a fusion tier raises; the ``DFFT_FUSE`` env default
+    is a preference and is ignored there, so a global ``DFFT_FUSE=1``
+    does not break ``xla`` plans. Whether fusion activates is then the
+    stage-graph gate (``stagegraph.plan_fusion``)."""
+    from dataclasses import replace
+
+    from ..plan_logic import resolve_fuse, resolve_tune_mode
+
+    ex = opts.executor
+    if opts.mm_precision is None and opts.mm_complex is None:
+        if ":" in ex:
+            base, tier, cmode = split_executor(ex)  # validates the label
+            # Canonical spelling ("matmul:high" -> "matmul:f32", the
+            # ":fuse" flag last): one label per tier across cache keys,
+            # wisdom, and stamps.
+            opts = replace(
+                opts, mm_precision=tier, mm_complex=cmode,
+                executor=fused_name(tiered_name(base, tier, cmode),
+                                    split_fuse(ex)[1] or None))
+    elif ex.split(":", 1)[0].startswith(MM_EXECUTOR_BASES):
+        name = tiered_name(ex, opts.mm_precision, opts.mm_complex)
+        _, tier, cmode = (split_executor(name) if ":" in name
+                          else (name, None, None))
+        opts = replace(opts, executor=name, mm_precision=tier,
+                       mm_complex=cmode)
+    elif resolve_tune_mode(opts.tune) == "off":
+        raise ValueError(
+            f"mm_precision/mm_complex scope the matmul-family executors "
+            f"{MM_EXECUTOR_BASES}; executor={ex!r} never consults them "
+            f"(use tune='measure'/'wisdom' to search the tiered "
+            f"candidate axis instead)")
+
+    ex = opts.executor
+    pinned = split_fuse(ex)[1] if ":" in ex else False
+    if opts.fuse is False and pinned:
+        raise ValueError(
+            f"executor {ex!r} already pins the fuse flag; fuse=False "
+            f"conflicts (drop one of the two spellings)")
+    if resolve_fuse(opts.fuse) and not pinned:
+        if ex.split(":", 1)[0] in FUSE_BASES:
+            ex = fused_name(ex, True)
+            pinned = True
+        elif opts.fuse is not None:
+            raise ValueError(
+                f"fuse=True scopes the Pallas-family executors "
+                f"{FUSE_BASES}; executor={ex!r} has no fusion tier "
+                f"(the DFFT_FUSE env default is ignored there)")
+    if ex == opts.executor and bool(opts.fuse) == pinned:
+        return opts
+    return replace(opts, executor=ex, fuse=pinned)
+
+
 #: Executor bases that lower through XLA's FFT ops — the family the
 #: fft-thunk guard below may substitute away from.
 THUNK_BASES = ("xla", "xla_minor")
@@ -219,7 +288,7 @@ THUNK_BASES = ("xla", "xla_minor")
 def thunk_guard_substitute(executor, *, decomposition: str, forward: bool,
                            uneven: bool, starved: bool = False):
     """The XLA:CPU fft-thunk retirement predicate, shared by the planners
-    (``api._thunk_guard_executor``) and the staged pipeline builders:
+    (``plan_logic.resolve_executor``) and the staged pipeline builders:
     with ``DFFT_THUNK_GUARD`` armed (an executor name, normally
     ``matmul``), an XLA-family executor on the CPU backend building one
     of the known-poisoned chain classes —
@@ -543,24 +612,7 @@ def _pallas_executor(x: Array, axes: Sequence[int], forward: bool = True) -> Arr
     from . import pallas_fft
 
     axes = tuple(axes)
-    c64 = jnp.dtype(x.dtype) == jnp.complex64 and x.size > 0
-    # Fuse a trailing 2D plane into one kernel launch (the templateFFT
-    # 2D-app role for the t0 stage): both axes transform through VMEM with
-    # one HBM read/write instead of two of each.
-    if (len(axes) >= 2 and c64
-            and {axes[-2] % x.ndim, axes[-1] % x.ndim}
-            == {x.ndim - 2, x.ndim - 1}):
-        if pallas_fft.eligible2d(x.shape[-2], x.shape[-1]):
-            x = pallas_fft.fft2_last(x, forward=forward)
-            axes = axes[:-2]
-        else:
-            # The per-axis kernels below take the plane: still Pallas, so
-            # not a pallas_fallback, but counted so the route stays seen.
-            from ..utils import metrics as _metrics
-
-            _metrics.inc("pallas_plane_per_axis", ny=x.shape[-2],
-                         nz=x.shape[-1])
-    if not c64:
+    if jnp.dtype(x.dtype) != jnp.complex64 or x.size == 0:
         for ax in axes:
             x = pallas_fft.fft_along_axis(x, ax, forward=forward)
         return x

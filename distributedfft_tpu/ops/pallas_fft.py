@@ -121,7 +121,8 @@ def eligible(n: int) -> bool:
 def _tile_rows(env_name: str, bytes_per_row: int, floor: int) -> int:
     """Shared tile-size model: power of two, >= ``floor``, VMEM-budgeted;
     ``env_name`` overrides for hardware tuning sweeps (single source for
-    the 1D and 2D kernels so budget changes cannot desynchronize them)."""
+    the row and column kernels so budget changes cannot desynchronize
+    them)."""
     import os
 
     env = os.environ.get(env_name)
@@ -202,7 +203,7 @@ def _mm(a, b):
 def _four_step_pass(a3r, a3i, w1r, w1i, tr, ti, w2r, w2i, g1=1, g2=1):
     """One four-step DFT pass contracting the factor dims of [rows, n1, n2]
     planes (the transform axis pre-split to (n1, n2) by the caller), shared
-    by the 1D and fused-2D kernels. With ``g1``/``g2`` > 1 the stage
+    by the row and column kernels and the fusion tier's. With ``g1``/``g2`` > 1 the stage
     matrices arrive block-diagonal (I_g (x) W, see ``_tables_np``) and the
     row dim is regrouped so each matmul contracts a full MXU-width g*n
     lanes instead of a sub-128 factor — the reshapes change the lane dim,
@@ -337,53 +338,6 @@ def _make_kernel(n1: int, n2: int, g1: int, g2: int):
     return kernel
 
 
-def _make_kernel2d(ny: int, nz: int, gy: tuple[int, int],
-                   gz: tuple[int, int]):
-    """Fused 2D kernel: FFT along Z then Y of one plane tile, both passes
-    staged through VMEM in ONE launch — the templateFFT 2D-app role (one
-    ``FFT_main`` covering the whole YZ plane, ``kernel_512x512x1.h``; the
-    t0 stage of the slab pipeline, ``fft_mpi_3d_api.cpp:466-522``). Where
-    the per-axis path writes the full array to HBM between axes, this
-    kernel transposes in VMEM: one HBM read and one write for the plane.
-    ``gy``/``gz`` are the per-axis block-diagonal pack factors (see
-    ``_packs``).
-
-    Blocks are 5D ``[bt, y1, y2, z1, z2]`` (both axes pre-split by the
-    caller); inter-axis data movement is done by transposes, and the
-    packed stage matmuls inside ``_four_step_pass`` regroup rows with
-    lane-changing reshapes — both are VMEM relayouts under Mosaic. Output blocks are ``[bt, ky2, ky1, kz2,
-    kz1]`` — flat (k2, k1) per axis is that axis's natural transformed
-    order, so the caller's view back to ``[batch, ny, nz]`` is free."""
-    y1, y2 = split_for(ny)
-    z1, z2 = split_for(nz)
-
-    def kernel(wy1r, wy1i, tyr, tyi, wy2r, wy2i,
-               wz1r, wz1i, tzr, tzi, wz2r, wz2i, xr, xi, yr, yi):
-        bt = xr.shape[0]
-        # Pass 1 over Z: rows = bt*y1*y2 (leading merge).
-        ar = xr[:].reshape(bt * y1 * y2, z1, z2)
-        ai = xi[:].reshape(bt * y1 * y2, z1, z2)
-        br, bi = _four_step_pass(ar, ai, wz1r[:], wz1i[:], tzr[:],
-                                 tzi[:], wz2r[:], wz2i[:],
-                                 g1=gz[0], g2=gz[1])
-        # [bt, y1, y2, kz2, kz1] -> [bt, kz2, kz1, y1, y2] (VMEM relayout).
-        br = br.reshape(bt, y1, y2, z2, z1).transpose(0, 3, 4, 1, 2)
-        bi = bi.reshape(bt, y1, y2, z2, z1).transpose(0, 3, 4, 1, 2)
-        # Pass 2 over Y: rows = bt*z2*z1.
-        br = br.reshape(bt * z2 * z1, y1, y2)
-        bi = bi.reshape(bt * z2 * z1, y1, y2)
-        cr, ci = _four_step_pass(br, bi, wy1r[:], wy1i[:], tyr[:],
-                                 tyi[:], wy2r[:], wy2i[:],
-                                 g1=gy[0], g2=gy[1])
-        # [bt, kz2, kz1, ky2, ky1] -> [bt, ky2, ky1, kz2, kz1].
-        cr = cr.reshape(bt, z2, z1, y2, y1).transpose(0, 3, 4, 1, 2)
-        ci = ci.reshape(bt, z2, z1, y2, y1).transpose(0, 3, 4, 1, 2)
-        yr[:] = cr
-        yi[:] = ci
-
-    return kernel
-
-
 @functools.partial(jax.jit, static_argnames=("n", "forward", "interpret"))
 def _fft_tiles(xr, xi, *, n: int, forward: bool, interpret: bool):
     """Batched length-n DFT of [batch, n] float32 re/im planes; batch must be
@@ -436,96 +390,6 @@ def _fft_tiles(xr, xi, *, n: int, forward: bool, interpret: bool):
         interpret=interpret,
     )(*consts, xr.reshape(batch, n1, n2), xi.reshape(batch, n1, n2))
     return yr.reshape(batch, n), yi.reshape(batch, n)
-
-
-def batch_tile_2d(ny: int, nz: int) -> int:
-    """Plane-batch rows per grid step for the fused 2D kernel (same budget
-    model as :func:`batch_tile` scaled by the full plane size)."""
-    return _tile_rows("DFFT_PALLAS_TILE2D", 4 * 4 * ny * nz, 1)
-
-
-# Largest ny*nz plane (float32 elements) the fused 2D kernel (and the
-# fusion tier's VMEM-resident block, ops/pallas_fuse.py) accepts: one
-# plane copy must fit the per-tile VMEM budget, since the kernel's working
-# set is ~a dozen live plane copies even at bt=1 (the measured stack model
-# behind _VMEM_BUDGET).
-_MAX_PLANE_ELEMS = _VMEM_BUDGET // 4
-
-
-def eligible2d(ny: int, nz: int) -> bool:
-    """Plane shapes the fused 2D kernel handles: single-kernel factors on
-    BOTH axes *and* a VMEM-bounded plane footprint; other planes take the
-    per-axis kernels.
-
-    Only the interpreter runs it. Compiled for a v5e (PR 21), a 512x512
-    plane overflows the 128 MiB of VMEM even at one plane per grid step,
-    and the planes that fit take minutes each to compile at their default
-    tile (256x256: 154 s, 128x256: 310 s, 64x64: over 900 s), so on the
-    TPU every plane takes the per-axis kernels, which ran on the chip at
-    512^3."""
-    return (_interpret_mode() and eligible(ny) and eligible(nz)
-            and ny * nz <= _MAX_PLANE_ELEMS)
-
-
-@functools.partial(jax.jit, static_argnames=("ny", "nz", "forward",
-                                             "interpret"))
-def _fft2_tiles(xr, xi, *, ny: int, nz: int, forward: bool, interpret: bool):
-    """Batched 2D DFT of [batch, ny, nz] float32 re/im planes; batch must
-    be a multiple of the tile size. Blocks travel pre-split as
-    [bt, y1, y2, z1, z2] (see ``_make_kernel2d``); outputs come back as
-    [batch, ky2, ky1, kz2, kz1] = [batch, ny, nz] flat."""
-    batch = xr.shape[0]
-    bt = min(batch_tile_2d(ny, nz), batch)
-    grid = batch // bt
-    y1, y2 = split_for(ny)
-    z1, z2 = split_for(nz)
-    gz = _packs(z1, z2, bt * y1 * y2)
-    gy = _packs(y1, y2, bt * z2 * z1)
-
-    tabs = []
-    for n, g in ((ny, gy), (nz, gz)):
-        w1, t, w2 = _tables_np(n, forward, *g)
-        tabs += [m for m in (w1, t, w2)]
-    consts = [jnp.asarray(p) for m in tabs for p in (m.real, m.imag)]
-    vma = _vma(xr)
-    if vma:
-        consts = [pvary(c, tuple(vma)) for c in consts]
-
-    lut_specs = [
-        pl.BlockSpec(m.shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
-        for m in tabs for _ in (0, 1)
-    ]
-    x_spec = pl.BlockSpec((bt, y1, y2, z1, z2), lambda i: (i, 0, 0, 0, 0),
-                          memory_space=pltpu.VMEM)
-    y_spec = pl.BlockSpec((bt, y2, y1, z2, z1), lambda i: (i, 0, 0, 0, 0),
-                          memory_space=pltpu.VMEM)
-
-    yr, yi = pl.pallas_call(
-        _make_kernel2d(ny, nz, gy, gz),
-        grid=(grid,),
-        in_specs=lut_specs + [x_spec, x_spec],
-        out_specs=(y_spec, y_spec),
-        out_shape=(
-            shape_dtype_struct((batch, y2, y1, z2, z1), jnp.float32,
-                               vma=vma),
-            shape_dtype_struct((batch, y2, y1, z2, z1), jnp.float32,
-                               vma=vma),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=8 * batch * ny * nz * (gy[0] * y1 + gy[1] * y2
-                                         + gz[0] * z1 + gz[1] * z2),
-            bytes_accessed=4 * batch * ny * nz * 4,
-            transcendentals=0,
-        ),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=_VMEM_LIMIT,
-        ),
-        interpret=interpret,
-    )(*consts,
-      xr.reshape(batch, y1, y2, z1, z2),
-      xi.reshape(batch, y1, y2, z1, z2))
-    return yr.reshape(batch, ny, nz), yi.reshape(batch, ny, nz)
 
 
 def _make_kernel_strided(n1: int, n2: int, g1: int, g2: int):
@@ -642,40 +506,6 @@ def fft_axis0(x: jnp.ndarray, forward: bool = True,
     if not forward and normalize:
         y = y * jnp.float32(1.0 / n)
     return y.reshape((n,) + rest)
-
-
-def fft2_last(x: jnp.ndarray, forward: bool = True) -> jnp.ndarray:
-    """Fused 2D C2C FFT over the LAST TWO axes of ``x`` (complex64, both
-    extents kernel-eligible — callers gate on :func:`eligible2d`). Forward
-    unnormalized, inverse scaled by 1/(ny*nz)."""
-    ny, nz = x.shape[-2:]
-    lead = x.shape[:-2]
-    batch = math.prod(lead) if lead else 1
-    x2 = x.reshape((batch, ny, nz))
-
-    bt = min(batch_tile_2d(ny, nz), max(1, batch))
-    pad = (-batch) % bt
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0), (0, 0)))
-    interpret = _interpret_mode()
-    if interpret and _vma(x2):
-        # CPU test backend under shard_map: the interpreter's grid loop
-        # cannot carry varying-axes types — per-axis jnp mirror, numerics
-        # identical to the kernel.
-        y = _four_step_ref(x2.reshape(-1, nz), nz, forward)
-        y = y.reshape(x2.shape)
-        y = jnp.swapaxes(y, -1, -2)
-        y = _four_step_ref(y.reshape(-1, ny), ny, forward)
-        y = jnp.swapaxes(y.reshape(x2.shape[0], nz, ny), -1, -2)
-    else:
-        yr, yi = _fft2_tiles(jnp.real(x2), jnp.imag(x2), ny=ny, nz=nz,
-                             forward=forward, interpret=interpret)
-        y = lax.complex(yr, yi)
-    if pad:
-        y = y[:batch]
-    if not forward:
-        y = y * jnp.float32(1.0 / (ny * nz))
-    return y.reshape(lead + (ny, nz))
 
 
 @functools.lru_cache(maxsize=None)

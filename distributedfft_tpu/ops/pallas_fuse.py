@@ -31,8 +31,7 @@ to the unfused chain by construction):
   count dividing the axis, and the whole local block VMEM-resident (one
   grid step — the per-(peer-tile, component-plane) amax reduction of the
   quantized codecs is a global reduction over the block, so the block
-  must be in VMEM at once; the same `_MAX_PLANE_ELEMS` bound as the
-  fused 2D kernel).
+  must be in VMEM at once, :data:`_MAX_BLOCK_ELEMS`).
 
 On the CPU test backend the mirrors also serve as the interpret-safe
 shard_map path (the :func:`.pallas_fft._fft_eligible` discipline); the
@@ -54,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils.compat import pvary, shape_dtype_struct, tpu_compiler_params
 from . import pallas_fft
 from .pallas_fft import (
-    _MAX_PLANE_ELEMS,
+    _VMEM_BUDGET,
     _VMEM_LIMIT,
     _interpret_mode,
     _tables_np,
@@ -62,6 +61,12 @@ from .pallas_fft import (
     eligible,
     split_for,
 )
+
+#: Largest local block (float32 elements) a fused kernel holds in VMEM:
+#: one block copy must fit the per-tile VMEM budget, since the kernel's
+#: working set is about a dozen live copies (the measured stack model
+#: behind ``pallas_fft._VMEM_BUDGET``).
+_MAX_BLOCK_ELEMS = _VMEM_BUDGET // 4
 
 #: Quantized codecs the in-kernel pack supports: name -> (signed levels,
 #: mantissa dtype). ``bf16`` is the cast-only codec (no amax reduction).
@@ -103,7 +108,7 @@ def kernel_ineligible(shape, fft_axis: int, tile_axis: int, tiles: int,
         return "length"
     if tiles < 1 or n % tiles:
         return "uneven_tiles"
-    if elems > _MAX_PLANE_ELEMS:
+    if elems > _MAX_BLOCK_ELEMS:
         return "vmem"
     return None
 

@@ -41,6 +41,8 @@ import os
 from dataclasses import dataclass, field, replace
 from typing import Sequence
 
+import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from . import geometry as geo
@@ -195,7 +197,7 @@ class PlanOptions:
                 f"tune must be one of {tuple(m for m in TUNE_MODES if m)} "
                 f"or None, got {self.tune!r}")
         # Normalize + validate the plan-scoped matmul tiers (the executor
-        # label is composed at plan time by api._apply_mm_tiers; this
+        # label is composed at plan time by executors.normalize_label; this
         # keeps an invalid tier from surviving into the plan cache key).
         from .ops.executors import (
             MM_COMPLEX_MODES, MM_TIERS, TIER_ALIASES,
@@ -810,6 +812,159 @@ def logic_plan3d(
         in_absorbed=in_absorbed, out_absorbed=out_absorbed,
         negotiated=negotiated, stages=stages, batch=batch,
     )
+
+
+# ------------------------------------------------------------ executors
+
+def resolve_options(
+    executor: str | None,
+    options: PlanOptions | None,
+    *,
+    decomposition: str | None = None,
+    algorithm: str = "alltoall",
+    donate: bool = False,
+    overlap_chunks: int | str | None = None,
+    tune: str | None = None,
+    wire_dtype: str | None = None,
+    max_roundtrip_err: float | None = None,
+    mm_precision: str | None = None,
+    mm_complex: str | None = None,
+    fuse: bool | str | None = None,
+) -> tuple[PlanOptions, bool]:
+    """A planner's options, and whether they name its executor.
+
+    ``options`` names the executor it holds. Otherwise ``executor`` does,
+    and ``DFFT_EXECUTOR`` (unless unset, ``0`` or ``none``) stands in
+    for an ``executor`` of ``None`` or ``"xla"``: the escape hatch for
+    environments whose XLA FFT lowering is broken (``matmul`` routes
+    every such plan through the thunk-free MXU engine). With nothing
+    named the options hold ``"xla"`` until :func:`resolve_executor`
+    settles the default on the logic plan. The label is normalized by
+    :func:`..ops.executors.normalize_label`."""
+    from .ops.executors import normalize_label
+
+    if options is not None:
+        if (decomposition is not None or executor not in (None, "xla")
+                or donate or algorithm != "alltoall"
+                or overlap_chunks is not None or tune is not None
+                or wire_dtype is not None or max_roundtrip_err is not None
+                or mm_precision is not None or mm_complex is not None
+                or fuse is not None):
+            raise ValueError(
+                "pass either options= or individual plan keywords, not both"
+            )
+        return normalize_label(options), True
+    if executor in (None, "xla"):
+        env = os.environ.get("DFFT_EXECUTOR", "").strip()
+        if env not in ("", "0", "none"):
+            executor = env
+    return normalize_label(PlanOptions(
+        decomposition=decomposition or "auto",
+        algorithm=algorithm,
+        executor=executor or "xla",
+        donate=donate,
+        overlap_chunks=overlap_chunks,
+        tune=tune,
+        wire_dtype=wire_dtype,
+        max_roundtrip_err=max_roundtrip_err,
+        mm_precision=mm_precision,
+        mm_complex=mm_complex,
+        fuse=fuse,
+    )), executor is not None
+
+
+def _on_tpu() -> bool:
+    """The backend predicate of the executor default."""
+    return jax.default_backend() == "tpu"
+
+
+def _thunk_guard(executor: str, lp: LogicPlan) -> str:
+    """The XLA:CPU fft-thunk retirement path on a logic plan
+    (:func:`..ops.executors.thunk_guard_substitute` is the shared
+    predicate; the staged pipeline builders apply the same rule). A
+    chain is uneven when some stage ceil-pads (shards of unequal
+    shape): the even pencil chains run the thunk cleanly. The slab class
+    is the minor-axis starved chain only: input slabs on axis 2 with
+    zero-extent shards. Starved chains on the major axes run the thunk
+    fine, and substituting there would break the executor-sensitive
+    bitwise-parity contracts for no protection."""
+    from .ops.executors import thunk_guard_substitute
+
+    if lp.mesh is None:
+        return executor
+    uneven = any(len({b.shape for b in boxes}) > 1
+                 for _axes, boxes in lp.stages)
+    starved = bool(
+        lp.decomposition == "slab" and lp.slab_axes
+        and lp.slab_axes[0] == 2
+        and any(0 in b.shape for b in lp.stages[0][1]))
+    return thunk_guard_substitute(
+        executor, decomposition=lp.decomposition, forward=lp.forward,
+        uneven=uneven, starved=starved)
+
+
+def _local_fft_blocks(lp: LogicPlan) -> list | None:
+    """Every local FFT of a c2c chain as a ``(block shape, axis)``: the
+    whole grid on each axis on one device. On a flat slab, t0's block
+    (the in-axis shard, the other two axes whole) on both of its axes,
+    and t3's block (the in-axis whole, the out-axis shard, each of the
+    overlap chunks of the third axis) on the in-axis. ``None`` for
+    chains never run on the plane passes: pencils and the hierarchical
+    transport's (dcn, ici) slab."""
+    from .parallel.exchange import overlap_chunk_bounds
+
+    shape = lp.shape
+    if lp.decomposition == "single":
+        return [(shape, ax) for ax in range(3)]
+    if lp.decomposition != "slab" or len(lp.mesh.axis_names) != 1:
+        return None
+    parts = math.prod(lp.mesh.devices.shape)
+    i, o = lp.slab_axes
+    c = 3 - i - o
+    t0 = list(shape)
+    t0[i] = -(-shape[i] // parts)
+    blocks = [(tuple(t0), ax) for ax in range(3) if ax != i]
+    for lo, hi in overlap_chunk_bounds(shape[c], lp.options.overlap_chunks):
+        t3 = list(shape)
+        t3[o] = -(-shape[o] // parts)
+        t3[c] = hi - lo
+        blocks.append((tuple(t3), i))
+    return blocks
+
+
+def resolve_executor(requested: str | None, lp: LogicPlan, *, dtype,
+                     transform: str = "c2c") -> tuple[str, str]:
+    """The executor a plan reports and the label its builders run.
+
+    ``requested`` is the executor the caller named (:func:`resolve_options`),
+    ``None`` if none. The fft-thunk guard comes first (:func:`_thunk_guard`:
+    an XLA-family executor on a poisoned CPU chain runs its substitute).
+    A named executor then runs as named. With nothing named, an unbatched
+    complex64 c2c transform (not an operator) on the TPU whose every local
+    FFT takes a plane pass (:func:`_local_fft_blocks`,
+    ``pallas_fft.plane_pass_eligible``) reports ``pallas`` and runs
+    ``pallas:highest``: exact, as XLA's FFT is, whatever
+    ``DFFT_MM_PRECISION`` says; counted in ``c2c_default_pallas``.
+    Everything else runs ``xla``. The Pallas import comes after the cheap
+    gates, so plans that cannot take the kernels never pay it."""
+    name = _thunk_guard(requested or "xla", lp)
+    if requested is not None or name != "xla":
+        return name, name
+    if (transform != "c2c" or lp.op is not None or lp.batch is not None
+            or jnp.dtype(dtype) != jnp.complex64 or not _on_tpu()):
+        return name, name
+    blocks = _local_fft_blocks(lp)
+    if blocks is None:
+        return name, name
+    from .ops import pallas_fft
+
+    if not all(pallas_fft.plane_pass_eligible(b, ax) for b, ax in blocks):
+        return name, name
+    from .utils import metrics as _metrics
+
+    _metrics.inc("c2c_default_pallas", decomposition=lp.decomposition,
+                 shape="x".join(map(str, lp.shape)))
+    return "pallas", "pallas:highest"
 
 
 def _grid_boxes(

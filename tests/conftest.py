@@ -22,7 +22,7 @@ os.environ.setdefault("DFFT_HW_PROFILE", "0")
 # sharded dispatch stream — for years the single fault cascaded into
 # ~177 collateral tier-1 failures. The guard routes exactly those
 # chain geometries through the matmul executor (dot_generals never
-# touch the FFT thunk; api._thunk_guard_executor documents the class),
+# touch the FFT thunk; plan_logic._thunk_guard documents the class),
 # so the fault never fires and every downstream 8-device test sees a
 # clean backend. Unset outside the suite: default planning is
 # HLO-identical to the unguarded build.

@@ -640,7 +640,7 @@ def test_thunk_guard_off_leaves_planning_untouched(monkeypatch):
     dfft.clear_plan_cache()
 
 
-def test_default_executor_env_escape_hatch(monkeypatch):
+def test_env_default_escape_hatch(monkeypatch):
     monkeypatch.setenv("DFFT_EXECUTOR", "matmul")
     dfft.clear_plan_cache()
     plan = dfft.plan_dft_c2c_3d(SHAPE, None, dtype=np.complex64)

@@ -407,33 +407,6 @@ def test_pallas_fallback_counter_labels():
         m.metrics_reset()
 
 
-@pytest.mark.parametrize("real_lowering", [False, True],
-                         ids=["interpreter", "tpu"])
-def test_pallas_plane_per_axis_is_counted_not_a_fallback(monkeypatch,
-                                                         real_lowering):
-    """A plane the fused 2D kernel does not take (over the VMEM cap in
-    the interpreter; every plane on the TPU) runs the per-axis kernels:
-    ``pallas_plane_per_axis`` counts it and ``pallas_fallback`` stays 0."""
-    from distributedfft_tpu.ops.executors import get_executor
-
-    shape = (2, 64, 64)
-    if real_lowering:
-        monkeypatch.setenv("DFFT_FORCE_REAL_LOWERING", "1")
-    else:
-        monkeypatch.setattr(pallas_fft, "_MAX_PLANE_ELEMS", 64 * 32)
-    assert not pallas_fft.eligible2d(*shape[1:])
-    m.metrics_reset()
-    m.enable_metrics()
-    try:
-        jax.eval_shape(lambda x: get_executor("pallas")(x, (1, 2), True),
-                       jax.ShapeDtypeStruct(shape, jnp.complex64))
-        assert m.counter_total("pallas_plane_per_axis") == 1.0
-        assert m.counter_total("pallas_fallback") == 0.0
-    finally:
-        m.enable_metrics(False)
-        m.metrics_reset()
-
-
 # ---------------------------------------------------- model pricing
 
 def test_fused_model_moves_fewer_hbm_bytes():

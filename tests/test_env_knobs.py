@@ -44,7 +44,7 @@ PLAN_AFFECTING = {
     "DFFT_AUTO_EXECUTORS", "DFFT_MM_PRECISION", "DFFT_MM_COMPLEX",
     "DFFT_MM_SPLIT", "DFFT_MM_DIRECT_MAX", "DFFT_DD_DEPTH",
     "DFFT_PALLAS_PACK", "DFFT_PALLAS_SPLIT", "DFFT_PALLAS_TILE",
-    "DFFT_PALLAS_TILE2D", "DFFT_PALLAS_TILE_STRIDED",
+    "DFFT_PALLAS_TILE_STRIDED",
     "DFFT_XLA_REAL", "DFFT_FORCE_REAL_LOWERING", "DFFT_OVERLAP",
     "DFFT_TUNE", "DFFT_WISDOM", "DFFT_TUNE_ITERS", "DFFT_TUNE_MAX",
     "DFFT_HW_PROFILE", "DFFT_TUNE_CORRECTION", "DFFT_WIRE_DTYPE",

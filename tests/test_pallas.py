@@ -146,35 +146,12 @@ def test_scheduler_feeds_kernel_splits():
         )
 
 
-# ------------------------------------------------------- fused 2D kernel
+# ------------------------------------------------------- the executor
 
-@pytest.mark.parametrize("shape", [(3, 64, 64), (2, 64, 128), (1, 128, 64)])
-def test_fft2_last_matches_numpy(shape):
-    """Fused 2D kernel (interpret mode) vs np.fft.fft2 on the last axes."""
-    from distributedfft_tpu.ops import pallas_fft
-
-    rng = np.random.default_rng(31)
-    x = (rng.standard_normal(shape) + 1j * rng.standard_normal(shape)).astype(
-        np.complex64)
-    got = np.asarray(pallas_fft.fft2_last(jnp.asarray(x)))
-    want = np.fft.fft2(x)
-    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 5e-6
-
-
-def test_fft2_last_inverse_roundtrip():
-    from distributedfft_tpu.ops import pallas_fft
-
-    rng = np.random.default_rng(32)
-    x = (rng.standard_normal((4, 64, 64))
-         + 1j * rng.standard_normal((4, 64, 64))).astype(np.complex64)
-    y = pallas_fft.fft2_last(jnp.asarray(x), forward=True)
-    back = np.asarray(pallas_fft.fft2_last(y, forward=False))
-    assert np.max(np.abs(back - x)) < 1e-5
-
-
-def test_pallas_executor_fuses_trailing_plane():
-    """The executor takes the fused path for trailing-plane axes and still
-    matches fftn."""
+def test_pallas_executor_matches_fftn_on_trailing_plane():
+    """The executor's complex64 path (one split, ``fft_planes`` on every
+    axis, one combine) matches fftn on the trailing plane and on all
+    three axes."""
     from distributedfft_tpu.ops.executors import get_executor
 
     rng = np.random.default_rng(33)

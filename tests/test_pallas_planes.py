@@ -16,7 +16,7 @@ from jax import lax
 from jax.sharding import Mesh
 
 import distributedfft_tpu as dfft
-from distributedfft_tpu import api
+from distributedfft_tpu import plan_logic
 from distributedfft_tpu import testing as tu
 from distributedfft_tpu.ops import pallas_fft
 from distributedfft_tpu.ops.executors import get_executor
@@ -34,19 +34,12 @@ def _reference(x, axes, forward):
     return f(x.astype(np.complex128), axes=axes)
 
 
-@pytest.fixture
-def tpu_routing(monkeypatch):
-    """Route planes as on the TPU, where no plane takes the fused 2D
-    kernel (``pallas_fft.eligible2d``)."""
-    monkeypatch.setattr(pallas_fft, "eligible2d", lambda ny, nz: False)
-
-
 @pytest.mark.parametrize("forward", [True, False], ids=["fwd", "bwd"])
 @pytest.mark.parametrize("axes", [(0, 1, 2), (1, 2), (0,)],
                          ids=["xyz", "yz", "x"])
 @pytest.mark.parametrize("shape", [(64, 64, 64), (32, 64, 128)],
                          ids=["64^3", "32x64x128"])
-def test_executor_matches_numpy(tpu_routing, shape, axes, forward):
+def test_executor_matches_numpy(shape, axes, forward):
     x = _rand_c64(shape)
     ex = get_executor("pallas")
     y = np.asarray(jax.jit(lambda v: ex(v, axes, forward))(x))
@@ -99,7 +92,7 @@ def test_user_input_is_not_overwritten():
     np.testing.assert_array_equal(np.asarray(x), x_host)
 
 
-def test_one_split_three_passes_one_combine(tpu_routing):
+def test_one_split_three_passes_one_combine():
     """The executor splits and combines once, not around every axis."""
     jaxpr = jax.make_jaxpr(lambda v: get_executor("pallas")(
         v, (0, 1, 2), True))(jax.ShapeDtypeStruct((128,) * 3, jnp.complex64))
@@ -114,7 +107,7 @@ def test_one_split_three_passes_one_combine(tpu_routing):
 @pytest.fixture
 def on_tpu(monkeypatch):
     """The planner sees a TPU backend (the kernels still interpret)."""
-    monkeypatch.setattr(api, "_on_tpu", lambda: True)
+    monkeypatch.setattr(plan_logic, "_on_tpu", lambda: True)
     monkeypatch.delenv("DFFT_EXECUTOR", raising=False)
     dfft.clear_plan_cache()
     m.metrics_reset()
@@ -180,7 +173,7 @@ def test_default_rule_keeps_xla(on_tpu, case):
 
 
 @pytest.mark.parametrize("case", ["slab_mesh", "slab_overlap_chunks"])
-def test_default_rule_picks_pallas_on_a_slab(on_tpu, tpu_routing, case):
+def test_default_rule_picks_pallas_on_a_slab(on_tpu, case):
     """A slab whose every local block takes the plane passes runs them:
     t0's block [n0/p, n1, n2] on axes 1 and 2, t3's [n0, n1/p, n2/K] on
     axis 0 (the backward chain's blocks are the mirror image)."""
@@ -221,7 +214,7 @@ def _dot_precisions(jaxpr):
 
 
 def test_default_plan_stays_exact_under_a_lower_precision_env(
-        on_tpu, tpu_routing, monkeypatch):
+        on_tpu, monkeypatch):
     """``DFFT_MM_PRECISION`` lowers the matmul-family executors' tier; the
     default plan, like the XLA FFT it replaces, stays exact: every
     contraction of its plane passes runs at HIGHEST. An explicit
@@ -244,7 +237,7 @@ def test_default_plan_stays_exact_under_a_lower_precision_env(
 
 
 def test_default_slab_plan_stays_exact_under_a_lower_precision_env(
-        on_tpu, tpu_routing, monkeypatch):
+        on_tpu, monkeypatch):
     """The slab twin of the test above: under ``DFFT_MM_PRECISION=default``
     every contraction of the default slab plan runs at HIGHEST, while an
     explicit ``pallas`` slab plan follows the env. (Inside ``shard_map``
@@ -269,7 +262,7 @@ def test_default_slab_plan_stays_exact_under_a_lower_precision_env(
     assert set(found) == {(lax.Precision.DEFAULT,) * 2}, found
 
 
-def test_default_executor_env_still_overrides(on_tpu, monkeypatch):
+def test_env_default_still_overrides(on_tpu, monkeypatch):
     monkeypatch.setenv("DFFT_EXECUTOR", "matmul")
     plan = dfft.plan_dft_c2c_3d((128,) * 3, None, dtype=jnp.complex64)
     assert plan.executor == "matmul"

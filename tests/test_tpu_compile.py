@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 import distributedfft_tpu as dfft
-from distributedfft_tpu import api
+from distributedfft_tpu import plan_logic
 from distributedfft_tpu.ops import dft_matmul, pallas_fft
 from distributedfft_tpu.ops.executors import get_executor
 from distributedfft_tpu.parallel.mesh import PENCIL_AXES, SLAB_AXIS
@@ -74,12 +74,10 @@ def real_kernels(monkeypatch):
     unsupported shape cast``)."""
     monkeypatch.setenv("DFFT_FORCE_REAL_LOWERING", "1")
     monkeypatch.setenv("DFFT_PALLAS_PACK", "0")
-    for f in (pallas_fft._fft_tiles, pallas_fft._fft2_tiles,
-              pallas_fft._fft_strided_tiles):
+    for f in (pallas_fft._fft_tiles, pallas_fft._fft_strided_tiles):
         f.clear_cache()
     yield
-    for f in (pallas_fft._fft_tiles, pallas_fft._fft2_tiles,
-              pallas_fft._fft_strided_tiles):
+    for f in (pallas_fft._fft_tiles, pallas_fft._fft_strided_tiles):
         f.clear_cache()
 
 
@@ -169,21 +167,6 @@ def test_plane_pass_compiles_each_kind_and_radix(one_chip, real_kernels,
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
-def test_pallas_2d_plane_kernel(one_chip, real_kernels, monkeypatch):
-    """On the TPU every plane takes the per-axis kernels: a 512x512 plane
-    exceeds the v5e's 128 MiB of VMEM, and the planes that fit take
-    minutes to compile at their default tile (``eligible2d``). The kernel
-    itself still compiles at a small plane, one plane per grid step."""
-    assert not pallas_fft.eligible2d(N, N)
-    assert not pallas_fft.eligible2d(64, 64)
-    monkeypatch.setenv("DFFT_PALLAS_TILE2D", "1")
-    x = jax.ShapeDtypeStruct((2, 64, 64), jnp.float32, sharding=one_chip)
-    compiled = jax.jit(lambda a, b: pallas_fft._fft2_tiles(
-        a, b, ny=64, nz=64, forward=True, interpret=False)).lower(
-            x, x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("mesh_shape", [(4,), (2, 2)],
                          ids=["slab", "pencil"])
 def test_distributed_512_compiles_on_four_chips(topo, mesh_shape):
@@ -203,7 +186,7 @@ def test_default_slab_plane_path_512_on_four_chips(topo, real_kernels,
     one complex64 split, the float32 planes through t2, three in-place
     plane passes, one combine, no FFT op, and no more temporary memory
     per chip than the ``xla`` plan, but for the passes' tables."""
-    monkeypatch.setattr(api, "_on_tpu", lambda: True)
+    monkeypatch.setattr(plan_logic, "_on_tpu", lambda: True)
     dfft.clear_plan_cache()
     mesh = Mesh(np.asarray(topo.devices).reshape(4), (SLAB_AXIS,))
     kw = dict(dtype=jnp.complex64,

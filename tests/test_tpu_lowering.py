@@ -26,12 +26,10 @@ def _fresh_kernel_traces():
     tune_pallas.py's sweep): clear them around every test or a cached
     trace from a previous test's env silently stands in for this one's
     — e.g. a PACK=0 test re-exporting the packed kernel."""
-    for f in (pallas_fft._fft_tiles, pallas_fft._fft2_tiles,
-              pallas_fft._fft_strided_tiles):
+    for f in (pallas_fft._fft_tiles, pallas_fft._fft_strided_tiles):
         f.clear_cache()
     yield
-    for f in (pallas_fft._fft_tiles, pallas_fft._fft2_tiles,
-              pallas_fft._fft_strided_tiles):
+    for f in (pallas_fft._fft_tiles, pallas_fft._fft_strided_tiles):
         f.clear_cache()
 
 
@@ -46,14 +44,6 @@ def test_fused_1d_lowers_for_tpu(n, monkeypatch):
     _export_ok(
         lambda a, b: pallas_fft._fft_tiles(
             a, b, n=n, forward=True, interpret=False), z, z)
-
-
-def test_fused_2d_plane_lowers_for_tpu(monkeypatch):
-    monkeypatch.setenv("DFFT_PALLAS_PACK", "1")
-    z = jnp.zeros((2, 512, 512), jnp.float32)
-    _export_ok(
-        lambda a, b: pallas_fft._fft2_tiles(
-            a, b, ny=512, nz=512, forward=True, interpret=False), z, z)
 
 
 def test_strided_lowers_for_tpu(monkeypatch):
